@@ -5,9 +5,10 @@ Closes the measured-vs-modelled loop for the spatial executor
 
 1. **Measure.**  Every layer of a VGG-style backbone is executed for real --
    the lax conv the unfused schedule runs, and the fused Pallas halo-conv
-   (``repro.kernels.halo_conv``, ``interpret=True`` on CPU CI) -- and timed
-   per shard row-count.  This yields genuine per-layer FLOP rates for the
-   machine the benchmark runs on.
+   (``repro.kernels.halo_conv``; compiled on a TPU, interpret mode on any
+   other backend) -- and timed per shard row-count.  This yields per-layer
+   FLOP rates for the device the benchmark runs on, which every result
+   names: a CPU run's times are not kernel timings.
 
 2. **Compose.**  The measured per-layer rates are composed into full-network
    makespans with the schedule algebra of paper eqs. 9-15, priced by the
@@ -58,6 +59,7 @@ from repro.core import AGX_XAVIER, Link  # noqa: E402
 from repro.core.replan import ComputeRateEstimator  # noqa: E402
 from repro.core.simulator import Sim  # noqa: E402
 from repro.kernels.halo_conv.halo_conv import halo_conv2d  # noqa: E402
+from repro.launch.mesh import device_summary  # noqa: E402
 from repro.models.vgg import VGGConfig  # noqa: E402
 from repro.spatial.halo import halo_sizes, shard_heights, spatial_alignment  # noqa: E402
 
@@ -202,8 +204,14 @@ def des_makespan(net, heights, rate_of, *, fused: bool, link: Link = LINK) -> fl
 
 def run_all(smoke: bool = False, out_path: str | None = "BENCH_spatial.json") -> dict:
     net = build_net(smoke)
-    repeats = 2 if smoke else 5
-    layers = measure_layers(net, interpret=True, repeats=repeats)
+    # Best of 10, not fewer: a busy host inflates the lax times the rates come
+    # from.  On an 8-core CPU host the smoke fused_speedup read 1.02-1.04 as
+    # best of 2 with its cores busy (1.04-1.09 idle), and 1.05-1.08 as best of
+    # 10 (1.11-1.14 idle).  Each timed call takes well under a millisecond.
+    repeats = 10
+    device = device_summary()
+    interpret = device["platform"] != "tpu"  # Pallas compiles only for the TPU
+    layers = measure_layers(net, interpret=interpret, repeats=repeats)
 
     equal = tuple([net.in_rows // N_SHARDS] * N_SHARDS)
     weighted = shard_heights(
@@ -248,6 +256,8 @@ def run_all(smoke: bool = False, out_path: str | None = "BENCH_spatial.json") ->
     err_calibrated = abs(pred_calibrated - truth) / truth
 
     out = dict(
+        device=device,
+        pallas_interpret=interpret,
         n_shards=N_SHARDS,
         caps=CAPS,
         link_bps=LINK.rate_bps,
@@ -268,6 +278,8 @@ def run_all(smoke: bool = False, out_path: str | None = "BENCH_spatial.json") ->
 
     print(f"\n== Spatial calibration: {len(net.layers)} layers, "
           f"{N_SHARDS} shards, caps {CAPS}, link {LINK.rate_bps/1e6:.0f} Mbps ==")
+    print(f"device: {device['platform']} {device['kind']} x{device['count']}"
+          + (" (Pallas in interpret mode: not kernel timings)" if interpret else ""))
     print(f"{'layer':10s} {'rows':>4s} {'lax (us)':>9s} {'pallas (us)':>11s} "
           f"{'GFLOP/s':>8s}")
     for L in layers:

@@ -14,7 +14,8 @@ non-overlapping BlockSpec blocks:
     out     [N, nT, TH, W_out, C_out]          -> block (1, 1, TH, W_out, TC)
 
 Grid: (N, nT, C_out / TC).  The wrapper picks TH so the per-step working set
-stays <= ~8 MB of VMEM.
+(tile-padded, double-buffered blocks plus f32 temporaries) stays within
+Mosaic's 16 MiB scoped-VMEM limit.
 
 Generality (the spatial fast path needs all of it -- see ISSUE/ROADMAP 5):
 

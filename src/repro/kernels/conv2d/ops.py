@@ -7,7 +7,9 @@ import jax.numpy as jnp
 
 from .conv2d import conv2d_tiles
 
-VMEM_BUDGET = 8 * 1024 * 1024  # bytes per grid step we allow ourselves
+# Mosaic's default scoped-VMEM limit is 16 MiB per kernel; keep a margin for
+# the relayout scratch the estimate below does not model.
+VMEM_BUDGET = 12 * 1024 * 1024
 
 
 def _pick_cout_tile(cout: int) -> int:
@@ -18,6 +20,30 @@ def _pick_cout_tile(cout: int) -> int:
     return 1  # pragma: no cover - range above always yields a divisor
 
 
+def _tiled(rows: int, lanes: int, itemsize: int = 4) -> int:
+    """Elements a [rows, lanes] slab occupies in VMEM: the last two dims of
+    every VMEM buffer are padded up to the native tile, (8, 128) for 32-bit
+    elements; narrower ones pack more rows per tile ((16, 128) for bf16,
+    (32, 128) for int8)."""
+    sub = 8 * 4 // itemsize
+    return -(-rows // sub) * sub * (-(-lanes // 128) * 128)
+
+
+def _vmem_bytes(
+    th: int, w_ext: int, cin: int, tc: int, k: int, itemsize: int, stride: int
+) -> int:
+    """Scoped VMEM one grid step of ``conv2d_tiles`` needs: the in/out blocks
+    and the weight block, each double-buffered by the pipeline, plus the
+    kernel's f32 temporaries (one tap's patch and the accumulator)."""
+    w_out = (w_ext - k) // stride + 1
+    x_blk = ((th - 1) * stride + k) * _tiled(w_ext, cin, itemsize)
+    w_blk = k * k * _tiled(cin, tc, itemsize)
+    o_blk = th * _tiled(w_out, tc, itemsize)
+    patch = th * _tiled(w_out, cin)
+    acc = _tiled(th * w_out, tc)
+    return 2 * (x_blk + w_blk + o_blk) * itemsize + (patch + acc) * 4
+
+
 def _pick_tile_h(
     h: int, w_ext: int, cin: int, cout: int, k: int, itemsize: int, stride: int = 1
 ):
@@ -26,16 +52,11 @@ def _pick_tile_h(
     final (remainder) tile and slice the surplus rows off, so a prime-height
     shard no longer collapses to 1-row tiles (nor -- worse -- silently loses
     its remainder rows; see tests/test_kernels.py)."""
+    tc = _pick_cout_tile(cout)
     for th in (64, 32, 16, 8, 4, 2, 1):
         if th > max(1, h):
             continue
-        tc = _pick_cout_tile(cout)
-        need = (
-            ((th - 1) * stride + k) * w_ext * cin
-            + k * k * cin * tc
-            + th * ((w_ext - k) // stride + 1) * tc
-        ) * max(itemsize, 4)
-        if need <= VMEM_BUDGET:
+        if _vmem_bytes(th, w_ext, cin, tc, k, itemsize, stride) <= VMEM_BUDGET:
             return th
     return 1
 

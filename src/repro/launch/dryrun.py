@@ -24,10 +24,6 @@ from pathlib import Path
 import jax
 import jax.numpy as jnp
 
-_CACHE_DIR = "/tmp/jax_compile_cache"
-jax.config.update("jax_compilation_cache_dir", _CACHE_DIR)
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
-
 from repro.configs import get, list_archs
 from repro.configs.steps import build
 from repro.launch.hlo_cost import analyze_hlo

@@ -6,12 +6,20 @@ from __future__ import annotations
 import jax
 
 __all__ = [
+    "device_summary",
     "make_production_mesh",
     "make_spatial_mesh",
     "mesh_axes",
     "dp_axes",
     "fsdp_axes",
 ]
+
+
+def device_summary() -> dict:
+    """The backend JAX actually brought up: platform, device kind, count."""
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs)}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

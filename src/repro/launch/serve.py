@@ -1,63 +1,95 @@
 """Serving launcher: HALP-partitioned VGG-16 (the paper's workload) or any
-vision arch, through the deadline-aware batching engine.
+vision arch, through the deadline-aware batching engine, at the arch's
+published size (``--smoke`` serves the reduced CPU-sized config instead).
 
     PYTHONPATH=src python -m repro.launch.serve --arch vgg16 --requests 32
+    PYTHONPATH=src python -m repro.launch.serve --arch vgg16 --smoke
     PYTHONPATH=src python -m repro.launch.serve --arch vit-l16 --requests 16
 """
 from __future__ import annotations
 
 import argparse
+import functools
 import time
 
+import jax
 
-def main():
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="vgg16")
-    ap.add_argument("--requests", type=int, default=32)
-    ap.add_argument("--max-batch", type=int, default=8)
-    ap.add_argument("--deadline-ms", type=float, default=500.0)
-    args = ap.parse_args()
+from repro.configs import get
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import device_summary
+from repro.runtime.serve import BatchingEngine, ServeConfig
 
-    import jax
-    import jax.numpy as jnp
 
-    from repro.configs import get
-    from repro.runtime.serve import BatchingEngine, ServeConfig
+def build_model(arch_name: str, *, smoke: bool = False, seed: int = 0):
+    """``(cfg, params, fn)``: the arch's config (published, or the smoke
+    config), random weights from ``seed``, and the batch -> logits function
+    the engine serves.  VGG-16 runs through the HALP plan (``plan_halp`` +
+    ``run_plan``) and then its classifier head.  The weights are an argument
+    of the jitted function, not constants folded into the executable (VGG-16's
+    are 0.5 GB, which would bloat the compile and the compile cache)."""
+    arch = get(arch_name)
+    cfg = arch.smoke_cfg if smoke else arch.cfg
+    params = arch.module.init(jax.random.PRNGKey(seed), cfg)
 
-    arch = get(args.arch)
-    cfg = arch.smoke_cfg
-    params = arch.module.init(jax.random.PRNGKey(0), cfg)
-
-    if args.arch == "vgg16":
+    if arch_name == "vgg16":
         from repro.core import plan_halp
         from repro.models import vgg
         from repro.spatial import run_plan
 
         plan = plan_halp(cfg.geom(), overlap_rows=4)
 
-        def model(batch):
+        def model(params, batch):
             feats = run_plan(plan, params["features"], vgg.apply_layer, batch)
             return vgg.head(params, feats)
-
-        print(f"serving vgg16 through the HALP plan ({len(plan.parts)} layers, "
-              f"3 collaborating segments)")
     else:
-        def model(batch):
+        def model(params, batch):
             return arch.module.apply(params, cfg, batch)
 
-    fn = jax.jit(model)
+    return cfg, params, functools.partial(jax.jit(model), params)
+
+
+def serve(fn, cfg, *, requests: int, max_batch: int, deadline_s: float,
+          seed: int = 1, observer=None) -> BatchingEngine:
+    """Submit ``requests`` random images (from ``seed``) and drain them
+    through a :class:`BatchingEngine` around ``fn``; returns the engine, whose
+    ``completed`` requests carry their payloads and results."""
+    eng = BatchingEngine(fn, ServeConfig(max_batch=max_batch), observer=observer)
     res = cfg.img_res
-    eng = BatchingEngine(fn, ServeConfig(max_batch=args.max_batch))
-    key = jax.random.PRNGKey(1)
-    t0 = time.monotonic()
-    for i in range(args.requests):
+    key = jax.random.PRNGKey(seed)
+    for _ in range(requests):
         key, k = jax.random.split(key)
-        eng.submit(jax.random.normal(k, (res, res, 3)), deadline_s=args.deadline_ms / 1e3)
-    stats = eng.run_until_drained()
+        eng.submit(jax.random.normal(k, (res, res, cfg.in_channels)),
+                   deadline_s=deadline_s)
+    eng.run_until_drained()
+    return eng
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--arch", default="vgg16")
+    ap.add_argument("--requests", type=int, default=32)
+    ap.add_argument("--max-batch", type=int, default=8)
+    ap.add_argument("--deadline-ms", type=float, default=500.0)
+    ap.add_argument("--smoke", action="store_true",
+                    help="serve the arch's reduced config (CPU examples, tests)")
+    args = ap.parse_args(argv)
+
+    enable_compile_cache()
+    dev = device_summary()
+    print(f"device: {dev['platform']} {dev['kind']} x{dev['count']}")
+    cfg, _params, fn = build_model(args.arch, smoke=args.smoke)
+    print(f"serving {args.arch} at {cfg.img_res} px"
+          + (" through the HALP plan" if args.arch == "vgg16" else ""))
+
+    t0 = time.monotonic()
+    eng = serve(fn, cfg, requests=args.requests, max_batch=args.max_batch,
+                deadline_s=args.deadline_ms / 1e3)
     wall = time.monotonic() - t0
+    stats = eng.stats()
     print(f"requests={stats['completed']} deadline_met={stats['deadline_met_frac']:.3f} "
           f"p50={stats['p50_latency_s']*1e3:.1f}ms p99={stats['p99_latency_s']*1e3:.1f}ms "
           f"throughput={stats['completed']/wall:.1f} req/s")
+    return stats
 
 
 if __name__ == "__main__":

@@ -25,7 +25,7 @@ Two compute engines:
   are the only consumers of ``ppermute`` data, so the overlap happens at
   kernel granularity (eqs. 9-15; docs/equations.md#fused-kernel).  Geometries
   the kernel cannot express (``p > k - s``, grouped non-depthwise convs) fall
-  back to the bit-compatible ``lax`` path.
+  back to the bit-compatible ``lax`` path with a warning.
 
 Capacity-weighted shards (``heights=...``): a pod mixing device generations
 deploys the *skewed* split the optimizer chose (``plan_even(ratios=...)``)
@@ -41,6 +41,7 @@ split exactly.
 """
 from __future__ import annotations
 
+import warnings
 from typing import Sequence
 
 import jax
@@ -292,6 +293,22 @@ def _pallas_supported(
     return groups == 1 or (groups == c == wts.shape[-1] and wts.shape[2] == 1)
 
 
+def _use_pallas(engine: str, k, s, p, groups, c, wts, w) -> bool:
+    """Whether ``engine`` selects the fused kernel for this geometry; a
+    ``"pallas"`` request the kernel cannot express runs the lax engine and
+    says so."""
+    if engine != "pallas":
+        return False
+    if _pallas_supported(k, s, p, groups, c, wts, w):
+        return True
+    warnings.warn(
+        f"conv2d_spatial(engine='pallas'): k={k} s={s} p={p} groups={groups} "
+        f"width={w} is outside the fused kernel; running the lax engine",
+        stacklevel=3,
+    )
+    return False
+
+
 def conv2d_spatial(
     x: jax.Array,
     params,
@@ -312,9 +329,10 @@ def conv2d_spatial(
 
     ``engine="pallas"`` fuses boundary-row packing + conv into one
     ``pallas_call`` (interior tiles never touch the halos -- the HALP overlap
-    at kernel granularity); unsupported geometries fall back to ``lax``.
+    at kernel granularity); unsupported geometries fall back to ``lax`` with
+    a warning.
     NOTE: ``pallas_call`` has no shard_map replication rule, so the enclosing
-    ``shard_map`` must pass ``check_rep=False`` when this engine is selected.
+    ``jax.shard_map`` must pass ``check_vma=False`` when this engine is selected.
     ``interpret=True`` runs the kernel in interpreter mode (CI / CPU).
     ``heights`` switches to the capacity-weighted padded layout (see module
     docstring)."""
@@ -329,7 +347,7 @@ def conv2d_spatial(
         raise ValueError(f"shard rows {hs} not divisible by stride {s}")
     lo, hi = halo_sizes(k, s, p)
 
-    if engine == "pallas" and _pallas_supported(k, s, p, groups, c, params["w"], w):
+    if _use_pallas(engine, k, s, p, groups, c, params["w"], w):
         # --- fused path: ppermute halos, then ONE kernel whose boundary tiles
         # are the only consumers of the remote rows (eqs. 9-15 fused).
         _check_halo_fits(hs, lo, hi)
@@ -414,7 +432,7 @@ def _conv2d_spatial_weighted(
     # both engines can overlap them with interior compute
     top, bot = _issue_halos_weighted(x, lo, hi, heights, hs_j, axis_name)
 
-    if engine == "pallas" and _pallas_supported(k, s, p, groups, c, wts, w):
+    if _use_pallas(engine, k, s, p, groups, c, wts, w):
         pad_rows = hi + (-(hmax + hi)) % s
         x_ext = (
             jnp.concatenate([x, jnp.zeros((b, pad_rows, w, c), x.dtype)], axis=1)

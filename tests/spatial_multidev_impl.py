@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 from functools import partial
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
@@ -76,7 +76,7 @@ fn = shard_map(
 check("depthwise 7x7", fn(x, params), want)
 
 # --- fused Pallas engine: same geometry sweep through ONE pallas_call --------
-# (pallas_call has no shard_map replication rule -> check_rep=False)
+# (pallas_call has no shard_map replication rule -> check_vma=False)
 key = jax.random.PRNGKey(21)
 for (k, s, p, c_in, c_out, h, g) in [
     (3, 1, 1, 3, 16, 64, 1),
@@ -97,7 +97,7 @@ for (k, s, p, c_in, c_out, h, g) in [
         mesh=mesh,
         in_specs=(P(None, "sp", None, None), P()),
         out_specs=P(None, "sp", None, None),
-        check_rep=False,
+        check_vma=False,
     )
     check(f"pallas conv k{k}s{s}p{p}g{g}", fn(x, params), want)
 
@@ -113,7 +113,7 @@ for engine in ("lax", "pallas"):
         mesh=mesh,
         in_specs=(P(None, "sp", None, None), P()),
         out_specs=P(None, "sp", None, None),
-        check_rep=False,
+        check_vma=False,
     )
     check(f"thin-shard k7 (t_hi < t_lo) {engine}", fn(x, params), want)
 
@@ -142,7 +142,7 @@ for (k, s, p, c_in, c_out, g) in [
             mesh=mesh,
             in_specs=(P(None, "sp", None, None), P()),
             out_specs=P(None, "sp", None, None),
-            check_rep=False,
+            check_vma=False,
         )
         got = merge_padded_shards(fn(xp, params), o_hts)
         check(f"weighted conv k{k}s{s}p{p}g{g} {engine} ov={overlap}", got, want)
@@ -169,7 +169,7 @@ for (k, s, p, c_in, c_out, g) in [
         mesh=mesh,
         in_specs=(P(None, "sp", None, None), P()),
         out_specs=P(None, "sp", None, None),
-        check_rep=False,
+        check_vma=False,
     )
     got = merge_padded_shards(fn(to_padded_shards(x, hts_tall), params),
                               tuple(hh // s for hh in hts_tall))
@@ -282,7 +282,7 @@ fn = shard_map(
     mesh=mesh,
     in_specs=(P(None, "sp", None, None), P()),
     out_specs=P(None, "sp", None, None),
-    check_rep=False,
+    check_vma=False,
 )
 got_w = merge_padded_shards(
     fn(to_padded_shards(x, hts_w), params_w["features"]),
@@ -315,7 +315,7 @@ pipe = shard_map(
     mesh=mesh,
     in_specs=(P("sp"), P()),       # one stage's weights per device
     out_specs=P(),                  # outputs valid on the last stage
-    check_rep=False,
+    check_vma=False,
 )
 got = pipe(ws, xs)
 check("pipeline 8-stage forward", got, ref, tol=1e-4)

@@ -2,6 +2,7 @@
 import sys
 from pathlib import Path
 
+import jax
 import pytest
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
@@ -114,6 +115,9 @@ def test_spatial_calibration_acceptance():
     from benchmarks import spatial_calibration
 
     out = spatial_calibration.run_all(smoke=True, out_path=None)
+    # the result names its device; Pallas compiles only on a TPU
+    assert out["device"]["platform"] == jax.devices()[0].platform
+    assert out["pallas_interpret"] == (out["device"]["platform"] != "tpu")
     # fused hides the halo latency behind interior compute: strictly faster
     assert out["fused_speedup"] >= 1.02, out["fused_speedup"]
     # weighted split keeps the slow shard from straggling (caps 1.0..0.35)

@@ -1,9 +1,6 @@
 """Property tests on the scheduling core + config registry invariants."""
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container image without hypothesis: deterministic shim
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (
     GTX_1080TI,

@@ -4,10 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container image without hypothesis: deterministic shim
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels.attention import attention_ref, flash_attention, gqa_flash
 from repro.kernels.conv2d import conv2d_pallas, conv2d_ref
@@ -31,6 +28,18 @@ CONV_CASES = [
     (2, 14, 14, 32, 64, 3, 1),  # VGG-16 deep-layer-like
     (1, 17, 13, 3, 8, 3, 1),  # odd sizes
 ]
+
+
+@pytest.mark.parametrize(
+    "itemsize,sublanes", [(4, 8), (2, 16), (1, 32)], ids=["f32", "bf16", "int8"]
+)
+def test_vmem_tile_packs_narrow_rows(itemsize, sublanes):
+    """A VMEM slab pads its last two dims to the dtype's native tile: narrow
+    dtypes pack more rows per tile, so a 3-row slab of them takes more."""
+    from repro.kernels.conv2d.ops import _tiled
+
+    assert _tiled(3, 3, itemsize) == sublanes * 128
+    assert _tiled(sublanes + 1, 129, itemsize) == 2 * sublanes * 256
 
 
 @pytest.mark.parametrize("case", CONV_CASES)

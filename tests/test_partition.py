@@ -1,9 +1,6 @@
 """Partitioner tests (paper §III eqs. 5-9 + HALP plan invariants)."""
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container image without hypothesis: deterministic shim
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.nets import vgg16_geom
 from repro.core.partition import (

@@ -2,10 +2,7 @@
 property-based losslessness of every placement the engine can emit."""
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container image without hypothesis: deterministic shim
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (
     GTX_1080TI,

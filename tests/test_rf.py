@@ -1,9 +1,6 @@
 """Receptive-field arithmetic tests (paper §II, eqs. 1-4, 8-9)."""
 import numpy as np
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container image without hypothesis: deterministic shim
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.rf import (
     LayerGeom,

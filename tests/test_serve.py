@@ -3,18 +3,13 @@ event-driven virtual-time loop (this subsystem's ``test_conformance.py``).
 
 Everything runs in simulated time -- there is no ``time.sleep`` anywhere and
 no wall-clock assertion; the :class:`~repro.runtime.serve.VirtualClock` and
-the trace loop's virtual event clock are the only notions of time.  The
-property tests run under real ``hypothesis`` when installed and under
-``tests/_hypothesis_fallback.py`` otherwise (same API subset)."""
+the trace loop's virtual event clock are the only notions of time."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # pragma: no cover - exercised only without hypothesis
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.reliability import (
     OffloadChannel,
@@ -643,3 +638,55 @@ def test_serve_trace_flash_crowd_shedding_protects_served_requests():
             shed.class_stats()[name]["deadline_met_frac"]
             >= noshed.class_stats()[name]["deadline_met_frac"]
         )
+
+
+def test_launcher_serves_smoke_vgg16(monkeypatch, capsys):
+    """``python -m repro.launch.serve --smoke`` serves the reduced VGG-16
+    through the HALP plan and names the backend it served on."""
+    from repro.launch import serve as launcher
+
+    monkeypatch.setattr(launcher, "enable_compile_cache", lambda: None)
+    # one batch width, so one compile of the plan
+    stats = launcher.main(["--smoke", "--requests", "2", "--max-batch", "2"])
+    assert stats["completed"] == 2
+    out = capsys.readouterr().out
+    dev = jax.devices()[0]
+    assert f"device: {dev.platform} {dev.device_kind} x{len(jax.devices())}" in out
+    assert "serving vgg16 at 64 px through the HALP plan" in out
+
+
+def test_launcher_model_matches_plain_forward():
+    """The launcher's served function (plan_halp -> run_plan -> head) gives
+    the logits of the plain single-device forward."""
+    from repro.launch.serve import build_model
+    from repro.models import vgg
+
+    cfg, params, fn = build_model("vgg16", smoke=True)
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, cfg.img_res, cfg.img_res, 3))
+    np.testing.assert_allclose(
+        np.asarray(fn(x)), np.asarray(vgg.apply(params, cfg, x)), rtol=1e-5, atol=1e-5
+    )
+
+
+@pytest.mark.parametrize("env_dir", [None, "/cache/placed/outside"])
+def test_compile_cache_placement(monkeypatch, env_dir):
+    """``JAX_COMPILATION_CACHE_DIR`` places the cache from outside and the
+    helper sets nothing; without it the cache is ``<checkout>/.jax_cache``."""
+    from pathlib import Path
+
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    try:
+        got = compile_cache.enable_compile_cache()
+        if env_dir is None:
+            root = Path(__file__).resolve().parents[1]
+            assert got == str(root / ".jax_cache")
+        else:
+            assert got == before  # left to JAX, which read the variable itself
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

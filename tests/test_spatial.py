@@ -308,7 +308,7 @@ def test_weighted_pallas_bottom_halo_overlapped():
     on the same geometry."""
     from functools import partial
 
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     from repro.models.common import conv_params
@@ -325,7 +325,7 @@ def test_weighted_pallas_bottom_halo_overlapped():
         mesh=mesh,
         in_specs=(P(None, "sp", None, None), P()),
         out_specs=P(None, "sp", None, None),
-        check_rep=False,
+        check_vma=False,
     )
     x = jax.random.normal(jax.random.PRNGKey(1), (1, 16, 8, 3))
 
@@ -369,3 +369,31 @@ def test_spmd_halo_exchange_multidevice():
     )
     assert res.returncode == 0, f"stdout:\n{res.stdout}\nstderr:\n{res.stderr}"
     assert "ALL MULTIDEV SPATIAL CHECKS PASSED" in res.stdout
+
+
+def test_pallas_engine_fallback_warns():
+    """A ``engine="pallas"`` request the fused kernel cannot express
+    (``p > k - s``) runs the lax engine -- bit-identical to asking for lax --
+    and says so instead of leaving the fallback silent."""
+    from functools import partial
+
+    from jax import shard_map
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from repro.models.common import conv_params
+    from repro.spatial import conv2d_spatial
+
+    params = conv_params(jax.random.PRNGKey(0), 3, 4, 8)
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, 8, 8, 4))
+    mesh = Mesh(np.array(jax.devices()[:1]), ("sp",))
+
+    def run(engine):
+        return shard_map(
+            partial(conv2d_spatial, k=3, s=2, p=2, axis_name="sp", engine=engine),
+            mesh=mesh, in_specs=(P(None, "sp", None, None), P()),
+            out_specs=P(None, "sp", None, None), check_vma=False,
+        )(x, params)
+
+    with pytest.warns(UserWarning, match="outside the fused kernel"):
+        got = run("pallas")
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(run("lax")))
