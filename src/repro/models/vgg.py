@@ -92,11 +92,16 @@ def features(params: Params, cfg: VGGConfig, x: jax.Array) -> jax.Array:
 
 
 def head(params: Params, x: jax.Array) -> jax.Array:
-    x = x.reshape(x.shape[0], -1)
-    hs = params["head"]
-    for p in hs[:-1]:
-        x = relu(dense(x, p))
-    return dense(x, hs[-1])
+    """Classifier logits; each dense layer under the scope ``head/fc<j>``."""
+    with jax.named_scope("head"):
+        x = x.reshape(x.shape[0], -1)
+        hs = params["head"]
+        for j, p in enumerate(hs, start=1):
+            with jax.named_scope(f"fc{j}"):
+                x = dense(x, p)
+                if j < len(hs):
+                    x = relu(x)
+        return x
 
 
 def apply(params: Params, cfg: VGGConfig, x: jax.Array) -> jax.Array:

@@ -46,15 +46,17 @@ def vit_block_init(key, d_model, n_heads, d_ff, dtype=jnp.float32) -> Params:
 def vit_block_apply(p: Params, x: jax.Array, n_heads: int) -> jax.Array:
     """Pre-LN transformer encoder block; x [B, N, D]."""
     b, n, d = x.shape
-    h = layernorm(x, p["ln1"])
-    qkv = dense(h, p["wqkv"]).reshape(b, n, 3, n_heads, d // n_heads)
-    q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-    logits = jnp.einsum("bnhd,bmhd->bhnm", q, k) / jnp.sqrt(d / n_heads)
-    probs = jax.nn.softmax(logits.astype(jnp.float32), -1).astype(x.dtype)
-    a = jnp.einsum("bhnm,bmhd->bnhd", probs, v).reshape(b, n, d)
-    x = x + dense(a, p["wo"])
-    h = layernorm(x, p["ln2"])
-    return x + dense(gelu(dense(h, p["fc1"])), p["fc2"])
+    with jax.named_scope("attn"):
+        h = layernorm(x, p["ln1"])
+        qkv = dense(h, p["wqkv"]).reshape(b, n, 3, n_heads, d // n_heads)
+        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        logits = jnp.einsum("bnhd,bmhd->bhnm", q, k) / jnp.sqrt(d / n_heads)
+        probs = jax.nn.softmax(logits.astype(jnp.float32), -1).astype(x.dtype)
+        a = jnp.einsum("bhnm,bmhd->bnhd", probs, v).reshape(b, n, d)
+        x = x + dense(a, p["wo"])
+    with jax.named_scope("mlp"):
+        h = layernorm(x, p["ln2"])
+        return x + dense(gelu(dense(h, p["fc1"])), p["fc2"])
 
 
 def init(key, cfg: ViTConfig, dtype=jnp.float32) -> Params:
@@ -76,10 +78,11 @@ def init(key, cfg: ViTConfig, dtype=jnp.float32) -> Params:
 def apply(params: Params, cfg: ViTConfig, x: jax.Array) -> jax.Array:
     """x [B, H, W, C] -> logits [B, classes]."""
     b = x.shape[0]
-    x = conv2d(x, params["patch_embed"], stride=cfg.patch, padding="VALID")
-    x = x.reshape(b, -1, cfg.d_model)
-    x = jnp.concatenate([jnp.broadcast_to(params["cls"], (b, 1, cfg.d_model)), x], axis=1)
-    x = x + params["pos"]
+    with jax.named_scope("patch_embed"):
+        x = conv2d(x, params["patch_embed"], stride=cfg.patch, padding="VALID")
+        x = x.reshape(b, -1, cfg.d_model)
+        x = jnp.concatenate([jnp.broadcast_to(params["cls"], (b, 1, cfg.d_model)), x], axis=1)
+        x = x + params["pos"]
 
     def body(h, p_l):
         return vit_block_apply(p_l, h, cfg.n_heads), None
@@ -87,8 +90,9 @@ def apply(params: Params, cfg: ViTConfig, x: jax.Array) -> jax.Array:
     if cfg.remat:
         body = jax.checkpoint(body, prevent_cse=False)
     x, _ = lax.scan(body, x, params["blocks"])
-    x = layernorm(x, params["ln"])
-    return dense(x[:, 0], params["head"])
+    with jax.named_scope("head"):
+        x = layernorm(x, params["ln"])
+        return dense(x[:, 0], params["head"])
 
 
 def loss_fn(params, cfg: ViTConfig, images, labels):

@@ -63,6 +63,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.reliability import OffloadChannel, required_slack, service_reliability
+from .tracing import SpanLog
 
 __all__ = [
     "Request",
@@ -149,6 +150,7 @@ class BatchingEngine:
         clock: Callable = time.monotonic,
         observer: Callable[[int, float], None] | None = None,
         es_observer: Callable[[str, float, float], None] | None = None,
+        spans: SpanLog | None = None,
     ):
         self.fn = fn
         self.cfg = cfg
@@ -160,6 +162,10 @@ class BatchingEngine:
         # chunk execution; wire ReplanController.observe_compute here to close
         # the compute side of the joint replan loop (see observe_es_time)
         self.es_observer = es_observer
+        # spans of every batch on ``clock`` (repro.runtime.tracing); with None
+        # the engine records nothing and opens no profiler annotation
+        self.spans = spans
+        self._steps = 0
         self.queue: list[Request] = []  # deadline-ordered heap (EDF)
         self.completed: list[Request] = []
         self._rid = 0
@@ -232,25 +238,60 @@ class BatchingEngine:
         return self.step() if self.ready() else []
 
     def step(self) -> list[Request]:
-        """Run one batch (earliest-deadline-first).  Returns completed reqs."""
-        batch = self._take_batch()
-        if not batch:
+        """Run one batch (earliest-deadline-first).  Returns completed reqs.
+
+        With a span log, the batch is one ``serve.step`` span, numbered from
+        1, whose own time is the EDF pop and the observer, around
+        ``serve.stack`` (padding and stacking the payloads), ``serve.call``
+        (the model call up to ``block_until_ready``) and ``serve.split``
+        (each request's result)."""
+        if not self.queue:
             return []
+        sp = self.spans
+        if sp is None:
+            batch = self._take_batch()
+            stacked = self._stack(batch)
+            out, t0, now = self._call(stacked)
+            self._observe(len(batch), t0, now)
+            return self._split(batch, out, now)
+        self._steps += 1
+        sid = self._steps
+        with sp.span("serve.step", sid) as info:
+            batch = self._take_batch()
+            info.update(rids=[r.rid for r in batch], width=len(batch),
+                        executed=self._executed(len(batch)))
+            with sp.span("serve.stack", sid):
+                stacked = self._stack(batch)
+            with sp.span("serve.call", sid):
+                out, t0, now = self._call(stacked)
+            self._observe(len(batch), t0, now)
+            with sp.span("serve.split", sid):
+                return self._split(batch, out, now)
+
+    def _executed(self, n: int) -> int:
+        """The width a batch of ``n`` requests runs at."""
+        return self.cfg.max_batch if self.cfg.pad_to_max else n
+
+    def _stack(self, batch: list[Request]):
         payloads = [r.payload for r in batch]
-        n = len(payloads)
-        if self.cfg.pad_to_max and n < self.cfg.max_batch:
-            payloads = payloads + [payloads[-1]] * (self.cfg.max_batch - n)
-        stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *payloads)
+        payloads += [payloads[-1]] * (self._executed(len(batch)) - len(batch))
+        return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *payloads)
+
+    def _call(self, stacked):
         t0 = self.clock()
         out = self.fn(stacked)
         jax.block_until_ready(out)
-        now = self.clock()
+        return out, t0, self.clock()
+
+    def _observe(self, n: int, t0: float, now: float) -> None:
         if self.observer is not None:
             # report the *executed* width: with pad_to_max the forward ran
-            # len(payloads) wide regardless of how many real requests were in
+            # max_batch wide regardless of how many real requests were in
             # it, and that is the size the measured latency corresponds to
             # (anything else would skew a replan controller's calibration)
-            self.observer(len(payloads), now - t0)
+            self.observer(self._executed(n), now - t0)
+
+    def _split(self, batch: list[Request], out, now: float) -> list[Request]:
         for i, r in enumerate(batch):
             r.done = now
             r.result = jax.tree_util.tree_map(lambda x: x[i], out)

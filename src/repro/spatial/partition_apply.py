@@ -132,10 +132,11 @@ def run_plan(
                 outs[es] = None
                 continue
             t0 = time.perf_counter() if time_observer else 0.0
-            y = segment_forward(
-                apply_layer, layer_params[i], g, avail[es][1], part.out[es],
-                avail[es][0], sizes[i],
-            )
+            with jax.named_scope(f"layer{i:02d}/{es}"):
+                y = segment_forward(
+                    apply_layer, layer_params[i], g, avail[es][1], part.out[es],
+                    avail[es][0], sizes[i],
+                )
             if time_observer:
                 jax.block_until_ready(y)
                 secs_acc[es] += time.perf_counter() - t0
@@ -144,27 +145,28 @@ def run_plan(
         if i + 1 == len(net.layers):
             break
         # message exchange: every ES's next-layer input = own rows + messages
-        new_avail = {}
-        for dst in es_names:
-            pieces: list[tuple[Segment, jax.Array]] = []
-            own = part.out[dst]
-            if own:
-                pieces.append((own, outs[dst]))
-            for src in es_names:
-                seg = plan.message(i, src, dst)
-                if seg:
-                    src_seg = part.out[src]
-                    sl = outs[src][:, seg.lo - src_seg.lo : seg.hi - src_seg.lo + 1]
-                    pieces.append((seg, sl))
-            if not pieces:  # ES owns no rows at this depth (tiny feature map)
-                new_avail[dst] = (Segment(1, 0), None)
-                continue
-            pieces.sort(key=lambda t: t[0].lo)
-            for (a, _), (b, _) in zip(pieces, pieces[1:]):
-                if b.lo != a.hi + 1:
-                    raise AssertionError(f"non-contiguous input for {dst} at layer {i}")
-            seg_all = Segment(pieces[0][0].lo, pieces[-1][0].hi)
-            new_avail[dst] = (seg_all, jnp.concatenate([t[1] for t in pieces], axis=1))
+        with jax.named_scope(f"layer{i:02d}/exchange"):
+            new_avail = {}
+            for dst in es_names:
+                pieces: list[tuple[Segment, jax.Array]] = []
+                own = part.out[dst]
+                if own:
+                    pieces.append((own, outs[dst]))
+                for src in es_names:
+                    seg = plan.message(i, src, dst)
+                    if seg:
+                        src_seg = part.out[src]
+                        sl = outs[src][:, seg.lo - src_seg.lo : seg.hi - src_seg.lo + 1]
+                        pieces.append((seg, sl))
+                if not pieces:  # ES owns no rows at this depth (tiny feature map)
+                    new_avail[dst] = (Segment(1, 0), None)
+                    continue
+                pieces.sort(key=lambda t: t[0].lo)
+                for (a, _), (b, _) in zip(pieces, pieces[1:]):
+                    if b.lo != a.hi + 1:
+                        raise AssertionError(f"non-contiguous input for {dst} at layer {i}")
+                seg_all = Segment(pieces[0][0].lo, pieces[-1][0].hi)
+                new_avail[dst] = (seg_all, jnp.concatenate([t[1] for t in pieces], axis=1))
         avail = new_avail
 
     if time_observer:
@@ -174,7 +176,8 @@ def run_plan(
 
     # final merge on the host (paper: sub-outputs -> FL input)
     ordered = sorted(es_names, key=lambda es: plan.parts[-1].out[es].lo)
-    return jnp.concatenate([outs[es] for es in ordered if plan.parts[-1].out[es]], axis=1)
+    with jax.named_scope("merge"):
+        return jnp.concatenate([outs[es] for es in ordered if plan.parts[-1].out[es]], axis=1)
 
 
 def _slice_last_axis(params, lo: int, hi: int):

@@ -167,3 +167,42 @@ def test_softmax_xent_matches_manual():
     want = -jnp.mean(jax.nn.log_softmax(logits)[jnp.arange(4), labels])
     got = softmax_xent(logits, labels)
     assert float(got) == pytest.approx(float(want), rel=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# named scopes of the served programs, as the compiled HLO's op_names carry them
+# ---------------------------------------------------------------------------
+
+
+def _compiled_op_names(arch: str) -> set[str]:
+    import re
+
+    from repro.launch.serve import build_model
+
+    cfg, params, fn = build_model(arch, smoke=True)
+    x = jax.ShapeDtypeStruct((2, cfg.img_res, cfg.img_res, cfg.in_channels), jnp.float32)
+    return set(re.findall(r'op_name="([^"]*)"', fn.func.lower(params, x).compile().as_text()))
+
+
+def test_vgg16_program_carries_layer_slot_exchange_and_head_scopes():
+    """``run_plan`` scopes each slot's segment ``layer<i>/<slot>``, the
+    messages ``layer<i>/exchange`` and the final concatenation ``merge``;
+    ``vgg.head`` its dense layers ``head/fc1-3``."""
+    from repro.configs import get
+    from repro.core import plan_halp
+
+    names = _compiled_op_names("vgg16")
+    plan = plan_halp(get("vgg16").smoke_cfg.geom(), overlap_rows=4)
+    for i, part in enumerate(plan.parts):
+        for es in plan.es_names:
+            if part.out[es]:
+                assert any(f"/layer{i:02d}/{es}/" in n for n in names), (i, es)
+    assert any("/exchange/" in n for n in names)
+    for j in (1, 2, 3):
+        assert any(f"/head/fc{j}/" in n for n in names), j
+
+
+def test_vit_program_carries_block_scopes():
+    names = _compiled_op_names("vit-l16")
+    for scope in ("attn", "mlp", "patch_embed", "head"):
+        assert any(f"/{scope}/" in n for n in names), scope

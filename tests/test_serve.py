@@ -188,6 +188,80 @@ def test_pad_to_max_reports_executed_width_variants():
 
 
 # ---------------------------------------------------------------------------
+# BatchingEngine spans (repro.runtime.tracing)
+# ---------------------------------------------------------------------------
+
+
+def _ticking_clock():
+    """A clock that moves one unit at every reading, so every span has a
+    length and nesting shows in the times."""
+    t = iter(range(10**9))
+    return lambda: float(next(t))
+
+
+def _engine(spans=None, clock=None):
+    eng = BatchingEngine(jax.jit(lambda b: b * 2.0), ServeConfig(max_batch=4),
+                         clock=clock or VirtualClock(), spans=spans)
+    for i in range(10):
+        eng.submit(jnp.ones((3,)) * i, deadline_s=5.0 - 0.1 * i)
+    return eng
+
+
+def test_engine_spans_nest_per_batch():
+    """One ``serve.step`` per batch, numbered from 1, holding the request ids
+    it served and its real and executed widths, with ``serve.stack``,
+    ``serve.call`` and ``serve.split`` of the same step inside it, in turn."""
+    from repro.runtime.tracing import SpanLog
+
+    clock = _ticking_clock()
+    log = SpanLog(clock)
+    eng = _engine(log, clock)
+    batches = [[r.rid for r in eng.step()] for _ in range(3)]
+    assert eng.step() == [] and not eng.queue
+    steps = [s for s in log.spans if s.name == "serve.step"]
+    assert [s.step for s in steps] == [1, 2, 3]
+    assert [s.info["rids"] for s in steps] == batches
+    assert [(s.info["width"], s.info["executed"]) for s in steps] == [(4, 4), (4, 4), (2, 4)]
+    for parent in steps:
+        kids = [s for s in log.spans if s.step == parent.step and s is not parent]
+        assert [k.name for k in kids] == ["serve.stack", "serve.call", "serve.split"]
+        assert parent.t0 < kids[0].t0 and kids[-1].t1 < parent.t1
+        assert all(a.t1 <= b.t0 for a, b in zip(kids, kids[1:]))
+    assert len(log.spans) == 12
+
+
+def test_engine_span_log_leaves_results_unchanged(monkeypatch):
+    """Batches and results with a span log equal those without; an engine
+    without one opens no profiler annotation."""
+    from repro.runtime.tracing import SpanLog
+
+    clock = VirtualClock()
+    plain, traced = _engine(), _engine(SpanLog(clock), clock)
+    opened = []
+    real = jax.profiler.TraceAnnotation
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                        lambda name, **kw: opened.append(name) or real(name, **kw))
+    a = plain.run_until_drained()
+    assert opened == []
+    b = traced.run_until_drained()
+    assert opened[:4] == ["serve.step", "serve.stack", "serve.call", "serve.split"]
+    assert a == b and len(traced.spans.spans) == 12
+    assert [r.rid for r in plain.completed] == [r.rid for r in traced.completed]
+    for x, y in zip(plain.completed, traced.completed):
+        np.testing.assert_array_equal(np.asarray(x.result), np.asarray(y.result))
+
+
+def test_build_model_records_setup_spans():
+    from repro.launch.serve import build_model
+    from repro.runtime.tracing import SpanLog
+
+    log = SpanLog()
+    build_model("vgg16", smoke=True, spans=log)
+    assert [(s.name, s.step) for s in log.spans] == [("build.init", None), ("build.plan", None)]
+    assert all(s.t0 <= s.t1 for s in log.spans)
+
+
+# ---------------------------------------------------------------------------
 # choose_batch_size properties (the PR-5 shed semantics, property-tested)
 # ---------------------------------------------------------------------------
 
@@ -653,6 +727,8 @@ def test_launcher_serves_smoke_vgg16(monkeypatch, capsys):
     dev = jax.devices()[0]
     assert f"device: {dev.platform} {dev.device_kind} x{len(jax.devices())}" in out
     assert "serving vgg16 at 64 px through the HALP plan" in out
+    for name, n in (("build.init", 1), ("build.plan", 1), ("serve.step", 1), ("serve.call", 1)):
+        assert f"span {name}: n={n} mean=" in out
 
 
 def test_launcher_model_matches_plain_forward():
