@@ -85,7 +85,9 @@ class Request:
     payload: Any = field(compare=False, default=None)
     arrival: float = field(compare=False, default=0.0)
     done: float | None = field(compare=False, default=None)
-    result: Any = field(compare=False, default=None)  # per-request model output
+    # this request's row of the batch's output, a host (numpy) array or a
+    # pytree of them: the engine copies each batch's output to the host once
+    result: Any = field(compare=False, default=None)
 
 
 @dataclass
@@ -140,8 +142,18 @@ class VirtualClock:
         return self._now
 
 
+# one compiled call per batch (one executable per payload shape and width)
+_stack_on_device = jax.jit(
+    lambda *rows: jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *rows))
+
+
 class BatchingEngine:
-    """Deadline-aware dynamic batcher around a jitted ``fn(batch_payloads)``."""
+    """Deadline-aware dynamic batcher around a jitted ``fn(batch_payloads)``.
+
+    Each batch costs a constant number of dispatches, whatever its width:
+    the payloads are stacked in one compiled call, and the output comes to
+    the host in one ``jax.device_get``, from which each request's
+    :attr:`Request.result` is its row, a numpy array."""
 
     def __init__(
         self,
@@ -240,11 +252,15 @@ class BatchingEngine:
     def step(self) -> list[Request]:
         """Run one batch (earliest-deadline-first).  Returns completed reqs.
 
+        The payloads are stacked once and the output is copied to the host
+        once per batch; each request's ``result`` is its row of that host
+        copy (padded rows are dropped).
+
         With a span log, the batch is one ``serve.step`` span, numbered from
         1, whose own time is the EDF pop and the observer, around
         ``serve.stack`` (padding and stacking the payloads), ``serve.call``
         (the model call up to ``block_until_ready``) and ``serve.split``
-        (each request's result)."""
+        (the copy to the host and each request's row)."""
         if not self.queue:
             return []
         sp = self.spans
@@ -273,9 +289,11 @@ class BatchingEngine:
         return self.cfg.max_batch if self.cfg.pad_to_max else n
 
     def _stack(self, batch: list[Request]):
+        """The batch's payloads, the last repeated up to the executed width,
+        stacked along a new leading axis in one compiled call."""
         payloads = [r.payload for r in batch]
         payloads += [payloads[-1]] * (self._executed(len(batch)) - len(batch))
-        return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *payloads)
+        return _stack_on_device(*payloads)
 
     def _call(self, stacked):
         t0 = self.clock()
@@ -292,9 +310,12 @@ class BatchingEngine:
             self.observer(self._executed(n), now - t0)
 
     def _split(self, batch: list[Request], out, now: float) -> list[Request]:
+        """Copies ``out`` to the host in one ``jax.device_get`` and gives
+        each request its row."""
+        host = jax.device_get(out)
         for i, r in enumerate(batch):
             r.done = now
-            r.result = jax.tree_util.tree_map(lambda x: x[i], out)
+            r.result = jax.tree_util.tree_map(lambda x: x[i, ...], host)
             self.completed.append(r)
         return batch
 

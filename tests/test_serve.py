@@ -251,6 +251,83 @@ def test_engine_span_log_leaves_results_unchanged(monkeypatch):
         np.testing.assert_array_equal(np.asarray(x.result), np.asarray(y.result))
 
 
+# Payload rows by kind: a row builder from request index, and its model.  The
+# pytree rows carry a Python float, so their host variant mixes leaf types.
+_ROWS = {
+    "scalar": (lambda i: np.float32(i) / np.float32(3),
+               lambda b: b * 2.0 + 1.0),
+    "array": (lambda i: np.arange(6, dtype=np.float32).reshape(2, 3) * np.float32(i / 7),
+              lambda b: jnp.tanh(b) @ jnp.full((3, 4), 0.5)),
+    "pytree": (lambda i: {"x": np.arange(3, dtype=np.float32) + i, "s": float(i) / 4},
+               lambda b: {"y": b["x"] * b["s"][:, None], "n": jnp.sum(b["x"], axis=1)}),
+}
+
+
+@pytest.mark.parametrize("rows", sorted(_ROWS))
+@pytest.mark.parametrize("pad", [True, False], ids=["pad", "nopad"])
+@pytest.mark.parametrize("where", ["device", "numpy"])
+def test_engine_results_are_host_rows_of_each_batch(where, pad, rows):
+    """Every request's result is, bit for bit, row ``i`` of its batch's
+    output computed from an eager ``jnp.stack`` of the padded payloads (the
+    per-request ``out[i]`` the engine used to return); every leaf is a host
+    array; padded rows reach no request."""
+    make, model = _ROWS[rows]
+    fn = jax.jit(model)
+    clk = VirtualClock()
+    eng = BatchingEngine(fn, ServeConfig(max_batch=4, pad_to_max=pad), clock=clk)
+    payloads = {}
+    for i in range(10):
+        row = make(i)
+        if where == "device":
+            row = jax.tree_util.tree_map(jnp.asarray, row)
+        payloads[eng.submit(row, deadline_s=5.0 - 0.1 * i)] = row
+    batches = [eng.step() for _ in range(3)]
+    assert [len(b) for b in batches] == [4, 4, 2] and not eng.queue
+    assert sorted(r.rid for r in eng.completed) == sorted(payloads)
+    for batch in batches:
+        padded = [payloads[r.rid] for r in batch]
+        padded += [padded[-1]] * ((4 if pad else len(batch)) - len(batch))
+        out = fn(jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *padded))
+        for i, r in enumerate(batch):
+            want = jax.tree_util.tree_map(lambda x: x[i], out)
+            assert jax.tree_util.tree_structure(r.result) == jax.tree_util.tree_structure(want)
+            for got, ref in zip(jax.tree_util.tree_leaves(r.result),
+                                jax.tree_util.tree_leaves(want)):
+                assert isinstance(got, np.ndarray) and not isinstance(got, jax.Array)
+                assert got.dtype == ref.dtype and got.shape == ref.shape
+                np.testing.assert_array_equal(got, np.asarray(ref))
+
+
+@pytest.mark.parametrize("pad,widths", [(True, (4, 2)), (False, (3, 3))],
+                         ids=["pad", "nopad"])
+def test_engine_second_batch_at_same_width_compiles_nothing(pad, widths):
+    """Stacking, the model call and the split compile on the first batch of
+    a width and never again: a later batch that runs at the same executed
+    width compiles nothing."""
+    compiles = []
+
+    def on_duration(event, _secs, **_kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+
+    eng = BatchingEngine(jax.jit(lambda b: jnp.tanh(b) * 3.0 + 1.0),
+                         ServeConfig(max_batch=4, pad_to_max=pad))
+    rows = jax.device_put(np.arange(40, dtype=np.float32).reshape(4, 5, 2))
+    rows = [rows[i] for i in range(4)]
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        seen = []
+        for n in widths:
+            for row in rows[:n]:
+                eng.submit(row, deadline_s=5.0)
+            assert len(eng.step()) == n
+            seen.append(len(compiles))
+            compiles.clear()
+        assert seen[0] > 0 and seen[1:] == [0], seen
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+
+
 def test_build_model_records_setup_spans():
     from repro.launch.serve import build_model
     from repro.runtime.tracing import SpanLog
