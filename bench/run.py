@@ -38,6 +38,7 @@ import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
+import re  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
@@ -243,6 +244,19 @@ class Readings:
                   + width * m["img_res"] ** 2 * m["in_channels"] * self.act_bytes
                   + width * m["num_classes"] * 4)
         return max(flops / self.peak["bf16_flops"], nbytes / self.peak["hbm_bytes_per_s"])
+
+    def scope_ms(self, scope: str) -> float | None:
+        """Device time per model program run, in ms, of the ops in the
+        program scopes that the regular expression ``scope`` matches whole,
+        and in their children (``head`` takes ``head/fc1``), over the runs
+        wholly inside the traced window.  ``None`` where no such op ran."""
+        t, mod = self.trace, self.model_module
+        if not t or not mod or not t["runs"].get(mod):
+            return None
+        want = re.compile(rf"(?:{scope})(?:/.*)?")
+        found = [v for k, v in t["scope_s"].items()
+                 if k.partition(":")[0] == mod and want.fullmatch(k.partition(":")[2])]
+        return sum(found) / t["runs"][mod] * 1e3 if found else None
 
 
 class Run:
@@ -621,6 +635,12 @@ def execute(spec: Spec, workload: str | dict, seed: int, seconds: float, trace: 
     if widths:
         log(f"real requests per batch in the window: mean {np.mean(widths):.3f}; "
             f"queued at the close: {run.backlog}")
+    if readings.trace and readings.trace["runs"].get(readings.model_module):
+        tops = {k.partition(":")[2].split("/")[0] for k in readings.trace["scope_s"]
+                if k.partition(":")[0] == readings.model_module}
+        by_top = {sc: readings.scope_ms(re.escape(sc)) for sc in tops}
+        log("device ms per model run by scope: " + ", ".join(
+            f"{sc} {v:.4f}" for sc, v in sorted(by_top.items(), key=lambda x: -x[1])))
     line = result_line(run, dev, trace, checks, e2e, readings)
     for name, c in checks.items():
         log(f"check {name} {c['value']!r} limit {c['limit']!r}")

@@ -8,29 +8,31 @@ from pathlib import Path
 
 BENCH = Path(__file__).resolve().parents[1]
 ROOT = BENCH.parent
-# the program's reduced configurations (repro.configs.*.SMOKE)
-SMOKE_MODELS = {
-    "vgg16-224": {"img_res": 64, "in_channels": 3, "num_classes": 10, "width_mult": 0.125,
-                  "blocks": [[2, 64], [2, 128], [3, 256], [3, 512], [3, 512]],
-                  "fc_dims": [4096, 4096]},
-    "vit-l16-224": {"img_res": 64, "in_channels": 3, "num_classes": 10, "patch": 8,
-                    "n_layers": 2, "d_model": 64, "n_heads": 4, "d_ff": 128},
-}
 
 
-def make_root(tmp: Path) -> tuple[Path, Path]:
-    """``(root, bench)``: the real ``BENCHMARK.json`` and data files, with
-    every configuration swapped for its smoke size."""
+def smoke_model(conf: dict) -> dict:
+    """A configuration's ``model`` keys at its architecture's smoke size, as
+    the program's registry gives it (``repro.configs.get(arch).smoke_cfg``)."""
+    from repro.configs import get
+
+    cfg = get(conf["arch"]).smoke_cfg
+    return {k: json.loads(json.dumps(getattr(cfg, k))) for k in conf["model"]}
+
+
+def make_root(tmp: Path, bench_src: Path = BENCH) -> tuple[Path, Path]:
+    """``(root, bench)``: ``BENCHMARK.json`` beside ``bench_src`` and its data
+    files, with every configuration in ``bench_src/configs`` swapped for its
+    smoke size."""
     bench = tmp / "bench"
     for d in ("counts", "reference", "traffic", "metrics"):
-        shutil.copytree(BENCH / d, bench / d)
-    shutil.copy(BENCH / "peaks.json", bench / "peaks.json")
+        shutil.copytree(bench_src / d, bench / d)
+    shutil.copy(bench_src / "peaks.json", bench / "peaks.json")
     (bench / "configs").mkdir()
-    for name, model in SMOKE_MODELS.items():
-        conf = json.loads((BENCH / "configs" / f"{name}.json").read_text())
-        conf.update(model=model, smoke=True)
-        (bench / "configs" / f"{name}.json").write_text(json.dumps(conf))
-    shutil.copy(ROOT / "BENCHMARK.json", tmp / "BENCHMARK.json")
+    for path in sorted((bench_src / "configs").glob("*.json")):
+        conf = json.loads(path.read_text())
+        conf.update(model=smoke_model(conf), smoke=True)
+        (bench / "configs" / path.name).write_text(json.dumps(conf))
+    shutil.copy(bench_src.parent / "BENCHMARK.json", tmp / "BENCHMARK.json")
     return tmp, bench
 
 

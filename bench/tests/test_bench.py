@@ -11,6 +11,7 @@ import functools
 import gzip
 import json
 import os
+import shutil
 import subprocess
 import sys
 from pathlib import Path
@@ -208,6 +209,39 @@ def test_a_mix_runs_on_a_config_at_a_given_rate(spec, tmp_path):
     assert np.percentile(z["done"] - z["due"], 95) * 1e3 == pytest.approx(e2e["p95_latency_ms"])
 
 
+@pytest.mark.parametrize("config,want", [
+    ("vgg16-224", {"img_res": 64, "in_channels": 3, "num_classes": 10, "width_mult": 0.125,
+                   "blocks": [[2, 64], [2, 128], [3, 256], [3, 512], [3, 512]],
+                   "fc_dims": [4096, 4096]}),
+    ("vit-l16-224", {"img_res": 64, "in_channels": 3, "num_classes": 10, "patch": 8,
+                     "n_layers": 2, "d_model": 64, "n_heads": 4, "d_ff": 128}),
+])
+def test_smoke_model_comes_from_the_registry(config, want):
+    conf = json.loads((BENCH / "configs" / f"{config}.json").read_text())
+    assert smoke.smoke_model(conf) == want
+
+
+def test_a_new_configuration_file_comes_out_at_its_smoke_size(tmp_path):
+    """A configuration of another registered architecture needs no edit of
+    the smoke copy: its file alone is enough."""
+    src = tmp_path / "src"
+    shutil.copytree(BENCH, src / "bench", ignore=shutil.ignore_patterns("tests", "__pycache__"))
+    shutil.copy(smoke.ROOT / "BENCHMARK.json", src / "BENCHMARK.json")
+    (src / "bench" / "configs" / "swin-b-384.json").write_text(json.dumps(
+        {"arch": "swin-b", "model": {
+            "img_res": 384, "in_channels": 3, "num_classes": 1000, "patch": 4, "window": 12,
+            "depths": [2, 2, 18, 2], "dims": [128, 256, 512, 1024], "n_heads": [4, 8, 16, 32],
+            "mlp_ratio": 4}}))
+    root, bench = smoke.make_root(tmp_path / "smoke", src / "bench")
+    assert sorted(p.name for p in (bench / "configs").iterdir()) == [
+        "swin-b-384.json", "vgg16-224.json", "vit-l16-224.json"]
+    conf = run.Spec(root, bench).config("swin-b-384")
+    assert conf["smoke"] is True
+    assert conf["model"] == {
+        "img_res": 64, "in_channels": 3, "num_classes": 10, "patch": 4, "window": 4,
+        "depths": [2, 2], "dims": [32, 64], "n_heads": [2, 4], "mlp_ratio": 4}
+
+
 def test_cell_file_overrides_its_mix(tmp_path):
     root, bench = smoke.make_root(tmp_path)
     doc = json.loads((root / "BENCHMARK.json").read_text())
@@ -338,19 +372,36 @@ def test_roofline_share_is_100_when_ops_take_the_least_time(spec):
     assert spec.reader("model_roofline.throughput").read(r) is None  # nothing to read
 
 
+def test_scope_metrics(spec):
+    r = _readings(spec, "vgg16-b1", [])
+    r.trace = {"runs": {"jit_model": 4, "jit_concatenate": 1}, "op_s": {}, "scope_s": {
+        "jit_model:head/fc1": 0.004, "jit_model:head/fc2": 0.0008, "jit_model:header": 1.0,
+        "jit_model:layer03/exchange": 0.0004, "jit_model:layer12/exchange": 0.0004,
+        "jit_model:layer03/e0": 1.0, "jit_model:attn": 0.012, "jit_model:(unscoped)": 0.002,
+        "jit_concatenate:(unscoped)": 1.0}}
+    assert spec.reader("head_ms.latency").read(r) == pytest.approx(1.2)  # with its children
+    assert spec.reader("exchange_ms.latency").read(r) == pytest.approx(0.2)
+    assert spec.reader("attn_ms.throughput").read(r) == pytest.approx(3.0)
+    assert spec.reader("unscoped_ms.throughput").read(r) == pytest.approx(0.5)
+    del r.trace["scope_s"]["jit_model:attn"]
+    assert spec.reader("attn_ms.throughput").read(r) is None  # nothing to read
+    r.trace = None
+    assert spec.reader("head_ms.latency").read(r) is None
+
+
 # -- trace reduction -----------------------------------------------------------------
 
 HLO = """HloModule jit_model, entry_computation_layout={()}
 
 %fused_computation.1 (param_0: bf16[8,16]) -> bf16[8,16] {
   %param_0 = bf16[8,16]{1,0} parameter(0)
-  ROOT %convolution.3 = bf16[8,16]{1,0} convolution(bf16[8,16]{1,0} %param_0, bf16[8,16]{1,0} %param_0), dim_labels=b0f_0io->b0f
+  ROOT %convolution.3 = bf16[8,16]{1,0} convolution(bf16[8,16]{1,0} %param_0, bf16[8,16]{1,0} %param_0), dim_labels=b0f_0io->b0f, metadata={op_name="jit(model)/while/body/closed_call/checkpoint/attn/bhnm,bmhd->bnhd/dot_general" stack_frame_id=3}
 }
 
 %fused_computation.2 (param_0.1: f32[8,16]) -> bf16[8,16] {
   %param_0.1 = f32[8,16]{1,0} parameter(0)
   %slice.1 = f32[8,16]{1,0} slice(f32[8,16]{1,0} %param_0.1), slice={[0:8], [0:16]}
-  ROOT %convert.1 = bf16[8,16]{1,0} convert(f32[8,16]{1,0} %slice.1)
+  ROOT %convert.1 = bf16[8,16]{1,0} convert(f32[8,16]{1,0} %slice.1), metadata={op_name="jit(model)/head/convert_element_type"}
 }
 
 %fused_computation.3 (param_0.2: bf16[8,16]) -> bf16[8,16] {
@@ -360,7 +411,7 @@ HLO = """HloModule jit_model, entry_computation_layout={()}
 
 ENTRY %main.9 (batch.1: f32[8,16]) -> bf16[8,16] {
   %batch.1 = f32[8,16]{1,0} parameter(0)
-  %fusion.2 = bf16[8,16]{1,0} fusion(f32[8,16]{1,0} %batch.1), kind=kLoop, calls=%fused_computation.2
+  %fusion.2 = bf16[8,16]{1,0} fusion(f32[8,16]{1,0} %batch.1), kind=kLoop, calls=%fused_computation.2, metadata={op_name="jit(model)/patch_embed/convert_element_type"}
   %fusion.1 = bf16[8,16]{1,0} fusion(bf16[8,16]{1,0} %fusion.2), kind=kOutput, calls=%fused_computation.1
   %copy-start = (f32[8,16]{1,0}, f32[8,16]{1,0}, u32[]) copy-start(f32[8,16]{1,0} %batch.1)
   %copy-done = f32[8,16]{1,0} copy-done((f32[8,16]{1,0}, f32[8,16]{1,0}, u32[]) %copy-start)
@@ -375,6 +426,35 @@ def test_classify():
     assert c["fusion.2"] == "move"
     assert c["fusion.3"] == "other"
     assert c["copy-start"] == c["copy-done"] == "move"
+
+
+@pytest.mark.parametrize("op_name,want", [
+    ("jit(model)/while/body/closed_call/checkpoint/attn/reduce_sum", "attn"),  # frames
+    ("jit(model)/remat/pjit/while/cond/mlp/add", "mlp"),
+    ("jit(model)/while/body/closed_call/checkpoint/attn/bhnm,bmhd->bnhd/transpose", "attn"),
+    ("jit(model)/head/fc1/jit(relu)/max", "head/fc1"),  # an inner jitted call
+    ("jit(model)/layer00/e1/jit(relu)", "layer00/e1"),
+    ("jit(model)/mlp/jit(_var)/square", "mlp"),
+    ("jit(model)/layer03/exchange/concatenate", "layer03/exchange"),
+    ("checkpoint/attn/reduce_sum", "attn"),  # inside a reduction's computation
+    ("jit(model)/while/body/dynamic_slice", "(unscoped)"),
+    ("jit(model)/jit(relu)", "(unscoped)"),
+    ("reduce_sum", "(unscoped)"),  # a bare primitive
+    ("params[\\'cls\\']", "(unscoped)"),  # a parameter's path
+    (None, "(unscoped)"),
+])
+def test_scope_of_an_op_name(op_name, want):
+    assert xplane.scope(op_name) == want
+
+
+def test_scopes_of_the_instructions():
+    sc = xplane.scopes(HLO)
+    assert sc["convolution.3"] == "attn"
+    assert sc["fusion.1"] == "attn"  # no op_name of its own: its root's
+    assert sc["fusion.2"] == "patch_embed"  # its own, not its root's
+    assert sc["convert.1"] == "head"
+    assert sc["fusion.3"] == sc["maximum.1"] == "(unscoped)"  # no op_name at all
+    assert sc["copy-start"] == "(unscoped)"
 
 
 def _ev(name, start, dur):
@@ -416,6 +496,19 @@ def test_reduce_busy_classes_and_gaps():
     assert gaps["fn"] == pytest.approx(50e-9)  # 600-650
     assert gaps["harness"] == pytest.approx(150e-9)  # 850-1000
     assert t["device_ops"][0] == ["jit_model/fusion.1", pytest.approx(200e-9)]
+    assert t["scope_s"] == {"jit_model:attn": pytest.approx(200e-9),
+                            "jit_model:patch_embed": pytest.approx(50e-9),
+                            "jit_model:(unscoped)": pytest.approx(100e-9),
+                            "jit_concatenate:(unscoped)": pytest.approx(50e-9)}
+    _assert_scopes_sum_to_classes(t)
+
+
+def _assert_scopes_sum_to_classes(t):
+    for mod in {k.partition(":")[0] for k in t["op_s"]}:
+        by_class = sum(v for k, v in t["op_s"].items() if k.partition(":")[0] == mod)
+        by_scope = sum(v for k, v in t["scope_s"].items() if k.partition(":")[0] == mod)
+        assert abs(by_scope - by_class) <= 1e-9
+    assert {k.partition(":")[0] for k in t["scope_s"]} == {k.partition(":")[0] for k in t["op_s"]}
 
 
 def test_reduce_without_device_plane_reads_nothing():
@@ -427,15 +520,19 @@ def test_reduce_without_device_plane_reads_nothing():
 RECORDED = BENCH / "tests" / "data"
 
 
+def _recorded(name):
+    from jax.profiler import ProfileData
+
+    hlo = gzip.decompress((RECORDED / f"{name}.hlo.txt.gz").read_bytes()).decode()
+    profile = ProfileData.from_serialized_xspace(
+        gzip.decompress((RECORDED / f"{name}.xplane.pb.gz").read_bytes()))
+    return xplane.reduce(profile, {"jit_model": hlo})
+
+
 def test_recorded_chip_trace(spec):
     """A trace recorded on a TPU v5 lite (0.1 s of ``vgg16-b8-saturate``:
     8 model runs through the engine), with the model program's HLO text."""
-    from jax.profiler import ProfileData
-
-    hlo = gzip.decompress((RECORDED / "vgg16-b8.hlo.txt.gz").read_bytes()).decode()
-    profile = ProfileData.from_serialized_xspace(
-        gzip.decompress((RECORDED / "vgg16-b8.xplane.pb.gz").read_bytes()))
-    t = xplane.reduce(profile, {"jit_model": hlo})
+    t = _recorded("vgg16-b8")
     assert 0 < t["busy_s"] <= t["window_s"]
     assert t["runs"]["jit_model"] == 8
     # per run: about 2.4 ms of conv and dot fusions, 0.2 ms moving data
@@ -446,3 +543,26 @@ def test_recorded_chip_trace(spec):
     labels = {n for n, _ in t["idle_gaps"]}
     assert labels == {"fn", "engine.step", "harness"}
     assert dict(t["idle_gaps"])["engine.step"] > 0.5 * t["window_s"]  # the engine's host time
+    # recorded before the program had named scopes: every op is unscoped
+    assert {k for k in t["scope_s"] if k.startswith("jit_model:")} == {"jit_model:(unscoped)"}
+    _assert_scopes_sum_to_classes(t)
+
+
+@pytest.mark.parametrize("name,cell,runs,tops,want", [
+    ("vit-l16-b8", "vit-l16-b8-saturate", 5, {"attn", "mlp", "patch_embed", "head", "(unscoped)"},
+     {"attn_ms.throughput": (2.789, 2.792), "unscoped_ms.throughput": (2.905, 2.906)}),
+    ("vgg16-b1", "vgg16-b1", 15, {"head", "merge", "(unscoped)"} | {f"layer{i:02d}" for i in range(18)},
+     {"head_ms.latency": (0.5542, 0.5544), "exchange_ms.latency": (0.01838, 0.01839)}),
+])
+def test_recorded_chip_trace_by_scope(spec, name, cell, runs, tops, want):
+    """Traces recorded on a TPU v5 lite (0.06 s of ``vit-l16-b8-saturate``,
+    0.04 s of ``vgg16-b1``), with the model program's HLO text: device time
+    by the program's scopes."""
+    t = _recorded(name)
+    assert t["runs"]["jit_model"] == runs
+    found = {k.partition(":")[2].split("/")[0] for k in t["scope_s"] if k.startswith("jit_model:")}
+    assert found == tops
+    _assert_scopes_sum_to_classes(t)
+    r = _readings(spec, cell, [], trace=t)
+    for metric, (lo, hi) in want.items():  # the range of the traced chip runs (PERF.md)
+        assert lo < spec.reader(metric).read(r) < hi

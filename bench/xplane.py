@@ -11,8 +11,10 @@ fusions or Pallas calls that contain them; ``move`` for instructions and
 fusions that only copy, slice, pad, concatenate, transpose, broadcast or
 cast; ``other`` for the rest (pools, norms, softmax, elementwise).  Loops
 (``while``, ``conditional``, ``call``) are left out of op times, since their
-bodies' ops are listed one by one.  The host plane holds the run's
-``TraceAnnotation`` spans, on the same clock.
+bodies' ops are listed one by one.  Each op also takes the program scope
+(``jax.named_scope``) its instruction's ``op_name`` names, so that device
+time can be read by the program's own layers.  The host plane holds the
+run's ``TraceAnnotation`` spans, on the same clock.
 """
 from __future__ import annotations
 
@@ -32,11 +34,17 @@ MOVE = {"copy", "copy-start", "copy-done", "slice", "dynamic-slice", "dynamic-up
 NEUTRAL = {"parameter", "constant", "tuple", "get-tuple-element", "iota"}
 NESTING = {"while", "conditional", "call"}
 TOP = 10
+# JAX's own frames in an op_name, beside jitted calls (``jit(relu)``) and
+# einsum specs (``bhnm,bmhd->bnhd``); none of them is a program scope
+FRAMES = {"while", "body", "cond", "closed_call", "checkpoint", "remat", "pjit"}
+UNSCOPED = "(unscoped)"
 
 _INSTR = re.compile(r"^(?:ROOT\s+)?%?([\w.\-]+) = .*? ([a-z][\w\-]*)\(")
 _TYPED = re.compile(r"^(?:ROOT\s+)?%?([\w.\-]+) = ([a-z]\w*)\[")
 _OPERAND = re.compile(r"%([\w.\-]+)")
 _CALLS = re.compile(r"(?:calls|to_apply|body|condition|branch_computations)=\{?%?([\w.\-]+)")
+_OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+_JIT = re.compile(r"jit\(.*\)")
 
 
 def op_name(event_name: str) -> str:
@@ -49,9 +57,9 @@ def module_name(event_name: str) -> str:
     return event_name.split("(", 1)[0]
 
 
-def classify(hlo_text: str) -> dict[str, str]:
-    """Class (``mxu``, ``move`` or ``other``) of every instruction of a
-    compiled module's HLO text, by name."""
+def _computations(hlo_text: str) -> dict[str, list[tuple[str, str, list[str], str]]]:
+    """``name, opcode, called computations, text`` of every instruction of a
+    compiled module's HLO text, by computation."""
     comps: dict[str, list[tuple[str, str, list[str], str]]] = {}
     cur = None
     for line in hlo_text.splitlines():
@@ -65,7 +73,13 @@ def classify(hlo_text: str) -> dict[str, str]:
             m = _INSTR.match(s)
             if m:
                 cur.append((m[1], m[2], _CALLS.findall(s), s))
+    return comps
 
+
+def classify(hlo_text: str) -> dict[str, str]:
+    """Class (``mxu``, ``move`` or ``other``) of every instruction of a
+    compiled module's HLO text, by name."""
+    comps = _computations(hlo_text)
     deep: dict[str, set[str]] = {}
 
     def opcodes(comp: str) -> set[str]:
@@ -94,6 +108,40 @@ def classify(hlo_text: str) -> dict[str, str]:
             else:
                 classes[name] = "other"
     return classes
+
+
+def scope(op_name: str | None) -> str:
+    """The program scope an ``op_name`` names: ``attn`` from
+    ``jit(model)/while/body/closed_call/checkpoint/attn/dot_general``.  The
+    last component (the primitive) goes, and so do JAX's frames, jitted
+    calls and einsum specs; ``(unscoped)`` where nothing is left."""
+    parts = (op_name or "").split("/")[:-1]
+    kept = [p for p in parts if p not in FRAMES and not _JIT.fullmatch(p) and "->" not in p]
+    return "/".join(kept) or UNSCOPED
+
+
+def scopes(hlo_text: str) -> dict[str, str]:
+    """Program scope of every instruction of a compiled module's HLO text,
+    by name, from its ``op_name``.  An instruction with no ``op_name`` of its
+    own (a fusion, a call) takes that of the root of the computation it
+    calls."""
+    comps = _computations(hlo_text)
+    instrs = {i[0]: i for body in comps.values() for i in body}
+    root = {c: i[0] for c, body in comps.items() for i in body if i[3].startswith("ROOT")}
+    named: dict[str, str | None] = {}
+
+    def op_name_of(name: str) -> str | None:
+        if name not in named:
+            named[name] = None  # a computation that calls itself names nothing
+            _, _, calls, text = instrs[name]
+            m = _OP_NAME.search(text)
+            if m:
+                named[name] = m[1]
+            elif calls and calls[0] in root:
+                named[name] = op_name_of(root[calls[0]])
+        return named[name]
+
+    return {name: scope(op_name_of(name)) for name in instrs}
 
 
 def operand_types(hlo_text: str) -> list[tuple[str, tuple[str, ...]]]:
@@ -132,8 +180,9 @@ def _merge(intervals):
 
 
 def reduce(profile, hlo: dict[str, str] | None = None) -> dict | None:
-    """Busy and window seconds, op seconds by module and class, executions
-    per module, the ops that took most time, and the idle gaps by host span.
+    """Busy and window seconds, op seconds by module and class and by module
+    and program scope, executions per module, the ops that took most time,
+    and the idle gaps by host span.
     Op times and executions count the program runs wholly inside the window;
     busy time is the union of all op intervals, clipped to it.
     ``profile`` is a ``jax.profiler.ProfileData``; ``hlo`` maps a module name
@@ -155,10 +204,11 @@ def reduce(profile, hlo: dict[str, str] | None = None) -> dict | None:
         return None
     w0, w1 = spans[WINDOW][0]
     classes = {m: classify(t) for m, t in (hlo or {}).items()}
+    scoped = {m: scopes(t) for m, t in (hlo or {}).items()}
     label_spans = {k: sorted(spans[k]) for k in LABELS}
 
-    busy_total, op_s, runs, by_op, gaps = 0.0, defaultdict(float), defaultdict(int), \
-        defaultdict(float), defaultdict(float)
+    busy_total, op_s, scope_s, runs, by_op, gaps = 0.0, defaultdict(float), defaultdict(float), \
+        defaultdict(int), defaultdict(float), defaultdict(float)
     n_ops = 0
     for plane in devices:
         lines = {line.name: line for line in plane.lines}
@@ -185,6 +235,7 @@ def reduce(profile, hlo: dict[str, str] | None = None) -> dict | None:
                 continue
             n_ops += 1
             op_s[(mod, cls)] += ev.duration_ns * 1e-9
+            scope_s[(mod, scoped.get(mod, {}).get(name, UNSCOPED))] += ev.duration_ns * 1e-9
             by_op[f"{mod}/{name}"] += ev.duration_ns * 1e-9
         merged = _merge(ivs)
         busy_total += sum(e - s for s, e in merged)
@@ -199,6 +250,7 @@ def reduce(profile, hlo: dict[str, str] | None = None) -> dict | None:
         "window_s": (w1 - w0) * 1e-9,
         "busy_s": busy_total * 1e-9 / k,
         "op_s": {f"{m}:{c}": v / k for (m, c), v in op_s.items()},
+        "scope_s": {f"{m}:{c}": v / k for (m, c), v in scope_s.items()},
         "runs": {m: n / k for m, n in runs.items()},
         "device_ops": [[n, v / k] for n, v in sorted(by_op.items(), key=lambda x: -x[1])[:TOP]],
         "idle_gaps": [[n, v / k] for n, v in sorted(gaps.items(), key=lambda x: -x[1])[:TOP]],
