@@ -1,22 +1,39 @@
 """Swin Transformer (Liu et al., arXiv:2103.14030) -- swin-b.
 
+The block follows the published equations and the authors' code: LayerNorm
+with eps 1e-5, the exact (erf) GELU, a learned relative-position bias, a
+shifted-window mask that adds -100 to logits across regions, and patch
+merging that concatenates the 2x2 neighbours as x0, x1, x2, x3
+(``x[0::2, 0::2]``, ``x[1::2, 0::2]``, ``x[0::2, 1::2]``, ``x[1::2, 1::2]``).
+
 Windowed attention has a *bounded receptive field*, so the paper's
 receptive-field partitioning applies directly: shifted windows need exactly a
 one-window halo, the transformer analogue of HALP's boundary exchange
 (see DESIGN.md §4).
+
+Named scopes, for the device trace: ``patch_embed``, ``stage<i>`` holding
+``window_attn`` (LN1, windows, attention, the first residual add), ``shift``
+(the cyclic rolls), ``mlp`` and ``merge``, then ``head``.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from .common import Params, conv_params, dense_params, keygen, norm_params, stack_layers, trunc_normal
-from .layers import conv2d, dense, gelu, layernorm, softmax_xent
+from .layers import conv2d, dense, layernorm, softmax_xent
 
 __all__ = ["SwinConfig", "init", "apply"]
+
+# nn.LayerNorm's default in the authors' code
+LN_EPS = 1e-5
+# added to the logits of key tokens from another region of a shifted window
+MASKED = -100.0
 
 
 @dataclass(frozen=True)
@@ -47,26 +64,34 @@ def _block_init(key, dim, heads, window, mlp_ratio, dtype):
     }
 
 
-def _rel_index(window: int) -> jax.Array:
-    """Relative-position index table for a window (static)."""
-    coords = jnp.stack(
-        jnp.meshgrid(jnp.arange(window), jnp.arange(window), indexing="ij"), 0
-    ).reshape(2, -1)
-    rel = coords[:, :, None] - coords[:, None, :]  # [2, n, n]
-    rel = rel + (window - 1)
-    return rel[0] * (2 * window - 1) + rel[1]  # [n, n]
+def _ln(x, p):
+    return layernorm(x, p, eps=LN_EPS)
 
 
-def _window_attention(p, x, heads, window, attn_mask=None):
-    """x: [B, nW, n, C] windows -> same shape."""
+def _gelu(x):
+    return jax.nn.gelu(x, approximate=False)
+
+
+@functools.cache
+def _rel_index(window: int) -> np.ndarray:
+    """Row of the relative-position bias table for each (query, key) pair of
+    a window, ``[n * n]``."""
+    coords = np.stack(np.meshgrid(np.arange(window), np.arange(window), indexing="ij")).reshape(2, -1)
+    rel = coords[:, :, None] - coords[:, None, :] + (window - 1)  # [2, n, n]
+    return (rel[0] * (2 * window - 1) + rel[1]).reshape(-1)
+
+
+def _window_attention(p, x, heads, window, mask=None):
+    """x: [B, nW, n, C] windows -> same shape; ``mask`` [nW, n, n] is added
+    to the logits."""
     b, nw, n, c = x.shape
     qkv = dense(x, p["wqkv"]).reshape(b, nw, n, 3, heads, c // heads)
     q, k, v = qkv[..., 0, :, :], qkv[..., 1, :, :], qkv[..., 2, :, :]
     logits = jnp.einsum("bwnhd,bwmhd->bwhnm", q, k) / jnp.sqrt(c / heads)
-    bias = p["rel_bias"][_rel_index(window).reshape(-1)].reshape(n, n, heads)
+    bias = p["rel_bias"][_rel_index(window)].reshape(n, n, heads)
     logits = logits + bias.transpose(2, 0, 1)[None, None]
-    if attn_mask is not None:  # [nW, n, n] boolean (True = keep)
-        logits = jnp.where(attn_mask[None, :, None], logits, -1e9)
+    if mask is not None:
+        logits = logits + mask[None, :, None]
     probs = jax.nn.softmax(logits.astype(jnp.float32), -1).astype(x.dtype)
     out = jnp.einsum("bwhnm,bwmhd->bwnhd", probs, v).reshape(b, nw, n, c)
     return dense(out, p["wo"])
@@ -88,38 +113,51 @@ def _from_windows(x, window, h, w):
     return x.reshape(b, h, w, c)
 
 
-def _shift_mask(h, w, window, shift) -> jax.Array:
-    """Attention mask for shifted windows: tokens attend only within their
-    original region (static, computed with numpy-style ops at trace time)."""
-    img = jnp.zeros((h, w), jnp.int32)
+@functools.cache
+def _shift_mask(h: int, w: int, window: int, shift: int) -> np.ndarray:
+    """Logit mask of shifted windows, ``[nW, n, n]``: 0 between tokens of one
+    region of the rolled image, ``MASKED`` across regions."""
+    img = np.zeros((h, w), np.int32)
     bounds = (slice(0, -window), slice(-window, -shift), slice(-shift, None))
-    cnt = 0
-    for hb in bounds:
-        for wb in bounds:
-            img = img.at[hb, wb].set(cnt)
-            cnt += 1
-    win = _to_windows(img[None, :, :, None].astype(jnp.float32), window)[0, :, :, 0]
-    return win[:, :, None] == win[:, None, :]  # [nW, n, n]
+    for i, hb in enumerate(bounds):
+        for j, wb in enumerate(bounds):
+            img[hb, wb] = 3 * i + j
+    win = _to_windows(img[None, :, :, None], window)[0, :, :, 0]
+    return np.where(win[:, :, None] == win[:, None, :], 0.0, MASKED).astype(np.float32)
+
+
+def _roll(x, shift):
+    with jax.named_scope("shift"):
+        return jnp.roll(x, (shift, shift), axis=(1, 2))
 
 
 def _swin_block(p, x, heads, window, shift):
     """x: [B, H, W, C]."""
     b, h, w, c = x.shape
-    shortcut = x
-    x = layernorm(x, p["ln1"])
+    with jax.named_scope("window_attn"):
+        y = _ln(x, p["ln1"])
     if shift:
-        x = jnp.roll(x, (-shift, -shift), axis=(1, 2))
-        mask = _shift_mask(h, w, window, shift)
-    else:
-        mask = None
-    xw = _to_windows(x, window)
-    xw = _window_attention(p, xw, heads, window, mask)
-    x = _from_windows(xw, window, h, w)
+        y = _roll(y, -shift)
+    with jax.named_scope("window_attn"):
+        mask = _shift_mask(h, w, window, shift) if shift else None
+        y = _window_attention(p, _to_windows(y, window), heads, window, mask)
+        y = _from_windows(y, window, h, w)
     if shift:
-        x = jnp.roll(x, (shift, shift), axis=(1, 2))
-    x = shortcut + x
-    h2 = layernorm(x, p["ln2"])
-    return x + dense(gelu(dense(h2, p["fc1"])), p["fc2"])
+        y = _roll(y, shift)
+    with jax.named_scope("window_attn"):
+        x = x + y
+    with jax.named_scope("mlp"):
+        y = dense(_ln(x, p["ln2"]), p["fc1"])
+        return x + dense(_gelu(y), p["fc2"])
+
+
+def _merge(stage, x):
+    """Patch merging: each 2x2 neighbourhood, as x0, x1, x2, x3, to the next
+    stage's width."""
+    b, h, w, c = x.shape
+    x = x.reshape(b, h // 2, 2, w // 2, 2, c).transpose(0, 1, 3, 4, 2, 5)
+    x = x.reshape(b, h // 2, w // 2, 4 * c)
+    return dense(_ln(x, stage["merge_norm"]), stage["merge"])
 
 
 def init(key, cfg: SwinConfig, dtype=jnp.float32) -> Params:
@@ -150,47 +188,48 @@ def init(key, cfg: SwinConfig, dtype=jnp.float32) -> Params:
     return p
 
 
+def _stage(stage, cfg: SwinConfig, si: int, x):
+    heads = cfg.n_heads[si]
+    hcur = x.shape[1]
+    # a stage no larger than one window attends over all of it, unshifted
+    shift = cfg.window // 2 if hcur > cfg.window else 0
+    win = min(cfg.window, hcur)
+    blocks = stage["blocks"]
+    depth = cfg.depths[si]
+    if depth >= 6 and depth % 2 == 0:
+        # scan over (regular, shifted) block *pairs* so HLO size stays bounded
+        pair = jax.tree_util.tree_map(lambda a: a.reshape(depth // 2, 2, *a.shape[1:]), blocks)
+
+        def pair_body(h, p_pair):
+            p0 = jax.tree_util.tree_map(lambda a: a[0], p_pair)
+            p1 = jax.tree_util.tree_map(lambda a: a[1], p_pair)
+            h = _swin_block(p0, h, heads, win, 0)
+            h = _swin_block(p1, h, heads, win, shift)
+            return h, None
+
+        if cfg.remat:
+            pair_body = jax.checkpoint(pair_body, prevent_cse=False)
+        x, _ = lax.scan(pair_body, x, pair)
+    else:
+        for li in range(depth):
+            p_l = jax.tree_util.tree_map(lambda a: a[li], blocks)
+            x = _swin_block(p_l, x, heads, win, shift if li % 2 else 0)
+    if "merge" in stage:
+        with jax.named_scope("merge"):
+            x = _merge(stage, x)
+    return x
+
+
 def apply(params: Params, cfg: SwinConfig, x: jax.Array) -> jax.Array:
-    b = x.shape[0]
-    x = conv2d(x, params["patch_embed"], stride=cfg.patch, padding="VALID")
-    x = layernorm(x, params["patch_norm"])
+    with jax.named_scope("patch_embed"):
+        x = conv2d(x, params["patch_embed"], stride=cfg.patch, padding="VALID")
+        x = _ln(x, params["patch_norm"])
     for si, stage in enumerate(params["stages"]):
-        heads = cfg.n_heads[si]
-        hcur = x.shape[1]
-        shift = cfg.window // 2 if hcur > cfg.window else 0
-        win = min(cfg.window, hcur)
-
-        # shallow stages unroll python-side; deep stages scan (regular, shifted)
-        # block *pairs* so HLO size stays bounded.
-        blocks = stage["blocks"]
-        depth = cfg.depths[si]
-        if depth >= 6 and depth % 2 == 0:
-            # scan over (regular, shifted) pairs to bound HLO size
-            pair = jax.tree_util.tree_map(
-                lambda a: a.reshape(depth // 2, 2, *a.shape[1:]), blocks
-            )
-
-            def pair_body(h, p_pair):
-                p0 = jax.tree_util.tree_map(lambda a: a[0], p_pair)
-                p1 = jax.tree_util.tree_map(lambda a: a[1], p_pair)
-                h = _swin_block(p0, h, heads, win, 0)
-                h = _swin_block(p1, h, heads, win, shift)
-                return h, None
-
-            if cfg.remat:
-                pair_body = jax.checkpoint(pair_body, prevent_cse=False)
-            x, _ = lax.scan(pair_body, x, pair)
-        else:
-            for li in range(depth):
-                p_l = jax.tree_util.tree_map(lambda a: a[li], blocks)
-                x = _swin_block(p_l, x, heads, win, shift if li % 2 else 0)
-        if "merge" in stage:  # patch merging: 2x2 neighbourhood -> next dim
-            bb, h, w, c = x.shape
-            x = x.reshape(bb, h // 2, 2, w // 2, 2, c).transpose(0, 1, 3, 2, 4, 5)
-            x = x.reshape(bb, h // 2, w // 2, 4 * c)
-            x = dense(layernorm(x, stage["merge_norm"]), stage["merge"])
-    x = layernorm(x, params["ln"])
-    return dense(jnp.mean(x, axis=(1, 2)), params["head"])
+        with jax.named_scope(f"stage{si}"):
+            x = _stage(stage, cfg, si, x)
+    with jax.named_scope("head"):
+        x = _ln(x, params["ln"])
+        return dense(jnp.mean(x, axis=(1, 2)), params["head"])
 
 
 def loss_fn(params, cfg: SwinConfig, images, labels):

@@ -65,10 +65,12 @@ def test_bubble_fraction():
 
 
 def test_registry_cells_total_40():
-    """The assigned pool: 10 archs x 4 shapes = 40 cells (+ vgg16 extra)."""
+    """The assigned pool: 10 archs x 4 shapes = 40 cells (+ vgg16 extra, and
+    swin-b-384, a served configuration with no assigned cells)."""
     from repro.configs import get, list_archs
 
-    assigned = [a for a in list_archs() if a != "vgg16"]
+    assert get("swin-b-384").cells == {}
+    assigned = [a for a in list_archs() if a != "vgg16" and get(a).cells]
     assert len(assigned) == 10
     total = sum(len(get(a).cells) for a in assigned)
     assert total == 40

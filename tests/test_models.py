@@ -206,3 +206,16 @@ def test_vit_program_carries_block_scopes():
     names = _compiled_op_names("vit-l16")
     for scope in ("attn", "mlp", "patch_embed", "head"):
         assert any(f"/{scope}/" in n for n in names), scope
+
+
+def test_swin_program_carries_stage_scopes():
+    """Each stage's blocks under ``stage<i>``: ``window_attn``, ``shift``,
+    ``mlp``, and ``merge`` between stages; ``patch_embed`` and ``head``."""
+    names = _compiled_op_names("swin-b-384")
+    for i in (0, 1):
+        for scope in ("window_attn", "shift", "mlp"):
+            assert any(f"/stage{i}/" in n and f"/{scope}/" in n for n in names), (i, scope)
+    assert any("/stage0/merge/" in n for n in names)
+    assert not any("/stage1/merge/" in n for n in names)  # the last stage merges nothing
+    for scope in ("patch_embed", "head"):
+        assert any(f"/{scope}/" in n for n in names), scope
