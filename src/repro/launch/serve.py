@@ -20,12 +20,25 @@ import functools
 import time
 
 import jax
+import jax.numpy as jnp
 
 from repro.configs import get
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import device_summary
 from repro.runtime.serve import BatchingEngine, ServeConfig
 from repro.runtime.tracing import SpanLog
+
+
+def _is_kernel(path) -> bool:
+    return getattr(path[-1], "key", None) == "w"
+
+
+@jax.jit
+def _bf16_kernels(params):
+    """``params`` with every leaf under the key ``"w"`` cast to bfloat16 (one
+    program for the whole tree, not one per kernel shape)."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, a: a.astype(jnp.bfloat16) if _is_kernel(path) else a, params)
 
 
 def build_model(arch_name: str, *, smoke: bool = False, seed: int = 0,
@@ -36,17 +49,27 @@ def build_model(arch_name: str, *, smoke: bool = False, seed: int = 0,
     ``run_plan``) and then its classifier head.  The weights are an argument
     of the jitted function, not constants folded into the executable (VGG-16's
     are 0.5 GB, which would bloat the compile and the compile cache).
+    Every conv and dense kernel (each leaf under the key ``"w"``) is kept in
+    bfloat16 from here on: at default precision the chip computes these
+    products with bf16 operands and f32 accumulation, the configurations'
+    stated operand type, so an f32 kernel would be rounded again on every
+    call; ``layers.conv2d`` and ``layers.dense`` take it as kept.  Biases,
+    norms, embeddings and tables stay f32.
     ``spans`` records the random init, to its last array, as ``build.init``
-    and the plan as ``build.plan``."""
+    (its ``info``: ``operand_bytes`` kept in bf16, ``weight_bytes`` of all
+    parameters) and the plan as ``build.plan``."""
     def span(name):
         return contextlib.nullcontext() if spans is None else spans.span(name)
 
     arch = get(arch_name)
     cfg = arch.smoke_cfg if smoke else arch.cfg
-    with span("build.init"):
-        params = arch.module.init(jax.random.PRNGKey(seed), cfg)
+    with span("build.init") as info:
+        params = _bf16_kernels(arch.module.init(jax.random.PRNGKey(seed), cfg))
         if spans is not None:
             jax.block_until_ready(params)
+            leaves = jax.tree_util.tree_leaves_with_path(params)
+            info["operand_bytes"] = sum(a.nbytes for path, a in leaves if _is_kernel(path))
+            info["weight_bytes"] = sum(a.nbytes for _, a in leaves)
 
     if arch_name == "vgg16":
         from repro.core import plan_halp

@@ -7,6 +7,14 @@ from jax import lax
 
 from .common import Params
 
+
+def _kept_narrower(w: jax.Array, x: jax.Array) -> bool:
+    """Whether the kernel ``w`` is kept in a narrower type than the activation
+    ``x`` (bf16 kernels, f32 activations): ``conv2d`` and ``dense`` then round
+    ``x`` to ``w``'s type and accumulate in ``x``'s, so the product takes the
+    kernel as it is kept and converts no weight on the call."""
+    return jnp.dtype(w.dtype).itemsize < jnp.dtype(x.dtype).itemsize
+
 # ---------------------------------------------------------------------------
 # conv / pool
 # ---------------------------------------------------------------------------
@@ -19,18 +27,21 @@ def conv2d(
     padding="SAME",
     groups: int = 1,
 ) -> jax.Array:
-    """NHWC x HWIO -> NHWC convolution."""
+    """NHWC x HWIO -> NHWC convolution (a kernel kept narrower than ``x``:
+    ``_kept_narrower``)."""
     s = (stride, stride) if isinstance(stride, int) else stride
     if isinstance(padding, int):
         padding = [(padding, padding), (padding, padding)]
+    w = p["w"]
+    narrow = _kept_narrower(w, x)
     y = lax.conv_general_dilated(
-        x,
-        p["w"],
+        x.astype(w.dtype) if narrow else x,
+        w,
         window_strides=s,
         padding=padding,
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
         feature_group_count=groups,
-        preferred_element_type=jnp.float32 if x.dtype == jnp.float32 else None,
+        preferred_element_type=x.dtype if narrow or x.dtype == jnp.float32 else None,
     )
     if "b" in p:
         y = y + p["b"]
@@ -62,7 +73,12 @@ def global_avg_pool(x):
 
 
 def dense(x, p: Params):
-    y = x @ p["w"]
+    """``x @ w + b`` (a kernel kept narrower than ``x``: ``_kept_narrower``)."""
+    w = p["w"]
+    if _kept_narrower(w, x):
+        y = jnp.matmul(x.astype(w.dtype), w, preferred_element_type=x.dtype)
+    else:
+        y = x @ w
     if "b" in p:
         y = y + p["b"]
     return y
