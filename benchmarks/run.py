@@ -1,5 +1,5 @@
-"""Benchmark entry point: one function per paper table/figure + the roofline
-tables derived from the dry-run artifacts.
+"""Benchmark entry point: one function per paper table/figure and the
+overlap ablation.
 
     PYTHONPATH=src python -m benchmarks.run
 
@@ -16,23 +16,13 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
 
 def main() -> None:
-    from benchmarks import ablation_overlap, paper_tables, roofline
+    from benchmarks import ablation_overlap, paper_tables
 
     print("#" * 72)
     print("# HALP paper reproduction (Li, Iosifidis, Zhang 2022)")
     print("#" * 72)
     paper_tables.run_all()
     ablation_overlap.run()
-
-    print()
-    print("#" * 72)
-    print("# Roofline analysis from the multi-pod dry-run (EXPERIMENTS.md)")
-    print("#" * 72)
-    for mesh in ("pod16x16", "pod2x16x16"):
-        if list(roofline.RESULTS.glob(f"*__{mesh}.json")):
-            roofline.print_table(mesh)
-        else:
-            print(f"(no dry-run results for {mesh}; run repro.launch.dryrun first)")
 
 
 if __name__ == "__main__":

@@ -2,7 +2,7 @@
 
 ``get(name)`` / ``list_archs()`` trigger registration lazily.
 """
-from .base import Arch, Cell, REGISTRY, get, list_archs  # noqa: F401
+from .base import Arch, REGISTRY, get, list_archs  # noqa: F401
 
 _LOADED = False
 

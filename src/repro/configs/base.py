@@ -1,28 +1,18 @@
-"""Arch registry: every assigned architecture is a selectable config exposing a
-uniform interface consumed by the launcher, the dry-run, the smoke tests and
-the serving/training drivers.
+"""Arch registry: every model configuration the system can serve or trace,
+selectable by name.
 
-An :class:`Arch` carries the *exact* assigned full config, a reduced smoke
-config (same family, tiny dims) and one :class:`Cell` per assigned input shape.
-``repro.configs.steps`` builds the jit-able step function + abstract input
-specs for any (arch, cell); ``repro.parallel.sharding`` owns the partitioning.
+An :class:`Arch` carries the *exact* published full config, a reduced smoke
+config (same family, tiny dims) and the model module whose ``init`` and
+``apply`` (``forward`` / ``decode_step`` for the LMs) build and run it.  The
+serving launcher (``repro.launch.serve.build_model``), the chip benchmark and
+the tests read ``cfg``, ``smoke_cfg`` and ``module``.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass, field
-from typing import Any, Callable
+from dataclasses import dataclass
+from typing import Any
 
-__all__ = ["Cell", "Arch", "REGISTRY", "register", "get", "list_archs"]
-
-
-@dataclass(frozen=True)
-class Cell:
-    """One assigned (architecture x input shape) cell."""
-
-    name: str
-    kind: str  # "train" | "prefill" | "decode" | "gen" | "serve"
-    meta: dict = field(default_factory=dict)  # batch, seq_len, img_res, steps...
-    skip: str | None = None  # reason if the cell is inapplicable (recorded)
+__all__ = ["Arch", "REGISTRY", "register", "get", "list_archs"]
 
 
 @dataclass(frozen=True)
@@ -31,8 +21,7 @@ class Arch:
     family: str  # "lm" | "vision" | "diffusion" | "convnet"
     cfg: Any
     smoke_cfg: Any
-    cells: dict[str, Cell]
-    module: Any  # the model module (init/apply/loss_fn)
+    module: Any  # the model module (init, apply or forward)
     notes: str = ""
 
 
@@ -58,46 +47,3 @@ def list_archs() -> list[str]:
 
     _load_all()
     return sorted(REGISTRY)
-
-
-# The assigned LM shape set (shared by the 4 LM archs).
-def lm_cells(*, full_attention: bool) -> dict[str, Cell]:
-    cells = {
-        "train_4k": Cell("train_4k", "train", {"seq_len": 4096, "global_batch": 256}),
-        "prefill_32k": Cell(
-            "prefill_32k", "prefill", {"seq_len": 32768, "global_batch": 32}
-        ),
-        "decode_32k": Cell(
-            "decode_32k", "decode", {"seq_len": 32768, "global_batch": 128}
-        ),
-        "long_500k": Cell(
-            "long_500k",
-            "decode",
-            {"seq_len": 524288, "global_batch": 1},
-            skip=(
-                "full-attention architecture; long_500k requires sub-quadratic "
-                "attention per the assignment (skip recorded in DESIGN.md)"
-                if full_attention
-                else None
-            ),
-        ),
-    }
-    return cells
-
-
-def vision_cells() -> dict[str, Cell]:
-    return {
-        "cls_224": Cell("cls_224", "train", {"img_res": 224, "batch": 256}),
-        "cls_384": Cell("cls_384", "train", {"img_res": 384, "batch": 64}),
-        "serve_b1": Cell("serve_b1", "serve", {"img_res": 224, "batch": 1}),
-        "serve_b128": Cell("serve_b128", "serve", {"img_res": 224, "batch": 128}),
-    }
-
-
-def diffusion_cells() -> dict[str, Cell]:
-    return {
-        "train_256": Cell("train_256", "train", {"img_res": 256, "batch": 256, "steps": 1000}),
-        "gen_1024": Cell("gen_1024", "gen", {"img_res": 1024, "batch": 4, "steps": 50}),
-        "gen_fast": Cell("gen_fast", "gen", {"img_res": 512, "batch": 16, "steps": 4}),
-        "train_1024": Cell("train_1024", "train", {"img_res": 1024, "batch": 32, "steps": 1000}),
-    }

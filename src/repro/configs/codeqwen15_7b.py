@@ -2,7 +2,7 @@
 vocab=92416.  [hf:Qwen/CodeQwen1.5-7B; hf]"""
 from ..models import transformer_lm as lm
 from ..models.transformer_lm import LMConfig
-from .base import Arch, lm_cells, register
+from .base import Arch, register
 
 FULL = LMConfig(
     name="codeqwen1.5-7b",
@@ -33,7 +33,6 @@ ARCH = register(
         family="lm",
         cfg=FULL,
         smoke_cfg=SMOKE,
-        cells=lm_cells(full_attention=True),
         module=lm,
         notes="dense MHA; qwen1.5 arch",
     )

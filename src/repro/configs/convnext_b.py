@@ -2,7 +2,7 @@
 [arXiv:2201.03545; paper]"""
 from ..models import convnext
 from ..models.convnext import ConvNeXtConfig
-from .base import Arch, register, vision_cells
+from .base import Arch, register
 
 FULL = ConvNeXtConfig(name="convnext-b", img_res=224, depths=(3, 3, 27, 3),
                       dims=(128, 256, 512, 1024))
@@ -15,7 +15,6 @@ ARCH = register(
         family="vision",
         cfg=FULL,
         smoke_cfg=SMOKE,
-        cells=vision_cells(),
         module=convnext,
         notes="pure sliding-window net; 7x7 depthwise = widest halos in the "
         "pool -- flagship for the spatial engine",

@@ -8,7 +8,7 @@ from ..models import transformer_lm as lm
 from ..models.attention import MLAConfig
 from ..models.moe import MoEConfig
 from ..models.transformer_lm import LMConfig
-from .base import Arch, lm_cells, register
+from .base import Arch, register
 
 FULL = LMConfig(
     name="deepseek-v3-671b",
@@ -52,9 +52,7 @@ ARCH = register(
         family="lm",
         cfg=FULL,
         smoke_cfg=SMOKE,
-        cells=lm_cells(full_attention=True),
         module=lm,
-        notes="MLA compressed KV cache (576/token); 256-way EP; bf16 optimizer "
-        "moments to fit 512 x 16 GB (DESIGN.md memory budget)",
+        notes="MLA compressed KV cache (576/token); 256 routed experts",
     )
 )

@@ -2,7 +2,7 @@
 n_heads=16.  [arXiv:2212.09748; paper]"""
 from ..models import dit
 from ..models.dit import DiTConfig
-from .base import Arch, diffusion_cells, register
+from .base import Arch, register
 
 FULL = DiTConfig(name="dit-xl2", img_res=256, patch=2, n_layers=28,
                  d_model=1152, n_heads=16)
@@ -15,9 +15,7 @@ ARCH = register(
         family="diffusion",
         cfg=FULL,
         smoke_cfg=SMOKE,
-        cells=diffusion_cells(),
         module=dit,
-        notes="latent diffusion transformer; gen shapes spatially shard the "
-        "latent height over the data axis (HALP SP applied to serving)",
+        notes="latent diffusion transformer",
     )
 )

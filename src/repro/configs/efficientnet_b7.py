@@ -2,7 +2,7 @@
 [arXiv:1905.11946; paper]"""
 from ..models import efficientnet
 from ..models.efficientnet import EfficientNetConfig
-from .base import Arch, register, vision_cells
+from .base import Arch, register
 
 FULL = EfficientNetConfig(name="efficientnet-b7", img_res=600,
                           width_mult=2.0, depth_mult=3.1)
@@ -15,7 +15,6 @@ ARCH = register(
         family="vision",
         cfg=FULL,
         smoke_cfg=SMOKE,
-        cells=vision_cells(),
         module=efficientnet,
         notes="MBConv+SE; HALP partitioning applies layer-wise, the SE global "
         "pool is the one cross-segment sync (DESIGN.md §4)",

@@ -3,7 +3,7 @@ vocab=163840, MoE 64e top-6.  [hf:moonshotai/Moonlight-16B-A3B; hf]"""
 from ..models import transformer_lm as lm
 from ..models.moe import MoEConfig
 from ..models.transformer_lm import LMConfig
-from .base import Arch, lm_cells, register
+from .base import Arch, register
 
 FULL = LMConfig(
     name="moonshot-v1-16b-a3b",
@@ -36,9 +36,7 @@ ARCH = register(
         family="lm",
         cfg=FULL,
         smoke_cfg=SMOKE,
-        cells=lm_cells(full_attention=True),
         module=lm,
-        notes="all-MoE (64e top-6, per-expert ff 1408); expert parallelism on "
-        "the model axis",
+        notes="all-MoE (64e top-6, per-expert ff 1408)",
     )
 )

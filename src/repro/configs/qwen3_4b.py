@@ -2,7 +2,7 @@
 qk_norm, head_dim=128.  [hf:Qwen/Qwen3-8B family; hf]"""
 from ..models import transformer_lm as lm
 from ..models.transformer_lm import LMConfig
-from .base import Arch, lm_cells, register
+from .base import Arch, register
 
 FULL = LMConfig(
     name="qwen3-4b",
@@ -35,7 +35,6 @@ ARCH = register(
         family="lm",
         cfg=FULL,
         smoke_cfg=SMOKE,
-        cells=lm_cells(full_attention=True),
         module=lm,
         notes="dense GQA with qk-norm; HALP spatial partitioning inapplicable "
         "(unbounded receptive field) -- runs DP/TP, see DESIGN.md §4",

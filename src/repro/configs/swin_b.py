@@ -6,7 +6,7 @@ import dataclasses
 
 from ..models import swin
 from ..models.swin import SwinConfig
-from .base import Arch, register, vision_cells
+from .base import Arch, register
 
 FULL = SwinConfig(name="swin-b", img_res=224, patch=4, window=7,
                   depths=(2, 2, 18, 2), dims=(128, 256, 512, 1024),
@@ -20,11 +20,10 @@ ARCH = register(
         family="vision",
         cfg=FULL,
         smoke_cfg=SMOKE,
-        cells=vision_cells(),
         module=swin,
         notes="bounded receptive field (7x7 windows): shifted windows need a "
         "one-window halo -- the transformer analogue of HALP's boundary "
-        "exchange (cls_384 uses window 12)",
+        "exchange (swin-b-384 uses window 12)",
     )
 )
 
@@ -35,9 +34,8 @@ ARCH_384 = register(
         family="vision",
         cfg=dataclasses.replace(FULL, name="swin-b-384", img_res=384, window=12),
         smoke_cfg=SMOKE,
-        cells={},
         module=swin,
-        notes="served configuration (no assigned cells): 9,216-token stage 1 "
+        notes="served configuration: 9,216-token stage 1 "
         "in 144-token shifted windows",
     )
 )

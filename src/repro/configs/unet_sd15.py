@@ -2,7 +2,7 @@
 n_res_blocks=2 attn_res=4-2-1 ctx_dim=768.  [arXiv:2112.10752; paper]"""
 from ..models import unet
 from ..models.unet import UNetConfig
-from .base import Arch, diffusion_cells, register
+from .base import Arch, register
 
 FULL = UNetConfig(name="unet-sd15", img_res=512, ch=320, ch_mult=(1, 2, 4, 4),
                   n_res_blocks=2, attn_down=(1, 2, 4), ctx_dim=768)
@@ -16,7 +16,6 @@ ARCH = register(
         family="diffusion",
         cfg=FULL,
         smoke_cfg=SMOKE,
-        cells=diffusion_cells(),
         module=unet,
         notes="conv path is sliding-window (paper partitioning direct); "
         "attention levels synchronise spatially",

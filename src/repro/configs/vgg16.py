@@ -1,8 +1,8 @@
-"""vgg16 -- the paper's own evaluation model (not part of the assigned pool;
-used by the HALP reproduction benchmarks and examples)."""
+"""vgg16 -- the paper's own evaluation model, served through the HALP plan
+and used by the reproduction benchmarks and examples."""
 from ..models import vgg
 from ..models.vgg import VGGConfig
-from .base import Arch, Cell, register
+from .base import Arch, register
 
 FULL = VGGConfig()
 SMOKE = VGGConfig(img_res=64, width_mult=0.125, num_classes=10)
@@ -13,9 +13,6 @@ ARCH = register(
         family="convnet",
         cfg=FULL,
         smoke_cfg=SMOKE,
-        cells={
-            "halp_224": Cell("halp_224", "serve", {"img_res": 224, "batch": 1}),
-        },
         module=vgg,
         notes="paper model; served through the HALP spatial engine",
     )

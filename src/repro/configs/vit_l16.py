@@ -2,7 +2,7 @@
 d_ff=4096.  [arXiv:2010.11929; paper]"""
 from ..models import vit
 from ..models.vit import ViTConfig
-from .base import Arch, register, vision_cells
+from .base import Arch, register
 
 FULL = ViTConfig(name="vit-l16", img_res=224, patch=16, n_layers=24,
                  d_model=1024, n_heads=16, d_ff=4096)
@@ -15,7 +15,6 @@ ARCH = register(
         family="vision",
         cfg=FULL,
         smoke_cfg=SMOKE,
-        cells=vision_cells(),
         module=vit,
         notes="conv stem is partitionable; global attention makes per-layer "
         "receptive field unbounded -> DP/TP (DESIGN.md §4)",

@@ -20,8 +20,7 @@ online, in three layers:
   :class:`ComputeRateEstimator`: an EWMA over observed per-ES execution
   times ``rate_sample = flops / elapsed``, seeded from each
   :class:`~repro.core.topology.Platform`'s calibrated ``eff_flops`` and fed
-  by the runtime's straggler stats (``runtime.fault``) and the serving
-  engine's per-ES timing hook (``runtime.serve``).
+  by the serving engine's per-ES timing hook (``runtime.serve``).
 
 * :class:`PlanCache` -- an LRU map from **(topology fingerprint + optimiser
   config, quantised rate buckets)** to the
@@ -235,9 +234,8 @@ class ComputeRateEstimator(_EwmaRateEstimator):
     FLOP/s) and moves that ES's estimate ``alpha`` of the way toward it.
     Estimates are seeded from each :class:`~repro.core.topology.Platform`'s
     calibrated ``eff_flops`` (host and secondaries alike), so an ES that is
-    never measured keeps behaving like its nominal.  Feeders: the runtime's
-    straggler tracking (:class:`~repro.runtime.fault.FaultTolerantTrainer`'s
-    ``compute_observer`` hook) and the serving engine's per-ES timing hook
+    never measured keeps behaving like its nominal.  Feeder: the serving
+    engine's per-ES timing hook
     (:meth:`~repro.runtime.serve.BatchingEngine.observe_es_time`)."""
 
     @classmethod

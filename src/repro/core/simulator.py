@@ -14,9 +14,8 @@ The HALP DAG itself is laid out by ``repro.core.events.build_halp_dag`` -- the
 same plan-walk the closed form prices -- so the two engines cross-validate on
 identical structure (``tests/test_schedule.py``).  Arbitrary
 :class:`~repro.core.topology.CollabTopology` instances are supported: N
-secondaries, per-ES platforms, per-link rates.  The same engine doubles as the
-straggler / fault-injection harness of the runtime (``repro.runtime.fault``):
-per-resource slowdown factors model node degradation at scale.
+secondaries, per-ES platforms, per-link rates.  Per-resource slowdown
+factors inject stragglers: they model node degradation at scale.
 """
 from __future__ import annotations
 

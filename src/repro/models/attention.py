@@ -80,7 +80,7 @@ def _sdpa(q, k, v, mask, scale):
 def _sdpa_chunked_causal(q, k, v, scale, chunk=Q_CHUNK):
     """Causal attention scanned over query blocks: peak memory is one
     [B, H, chunk, S] logits block instead of [B, H, T, S] (the pure-JAX
-    flash-equivalent used by 4k-train / 32k-prefill shapes; the Pallas TPU
+    flash-equivalent used by long prefill shapes; the Pallas TPU
     kernel in repro.kernels.attention is the on-device analogue)."""
     b, t, h, d = q.shape
     hkv = k.shape[2]
@@ -121,7 +121,7 @@ def gqa_apply(
 ):
     """Returns (out, (k_cache, v_cache)).
 
-    Training: kv=None, mask [B,1,1,T,T] causal.  Decode: kv = full caches
+    Forward (prefill): kv=None, mask [B,1,1,T,T] causal.  Decode: kv = full caches
     [B,S,max] and ``cache_index`` the write position; x is the new token block.
     """
     b, t, _ = x.shape

@@ -6,8 +6,9 @@ Models are pure functions over nested-dict parameter pytrees:
     params = init(jax.random.PRNGKey(0), cfg) # pytree of jnp arrays
     y      = apply(params, x, cfg)            # pure function
 
-No Module system -- pjit/shard_map distribute pure functions, and parameter
-sharding rules (repro.parallel.sharding) pattern-match on pytree paths.
+No Module system: ``jax.jit`` and ``shard_map`` take the pure functions as
+they are, and ``jax.eval_shape`` traces a full published config without
+allocating it.
 """
 from __future__ import annotations
 
@@ -77,16 +78,11 @@ def count_params(params: Params) -> int:
     return sum(x.size for x in jax.tree_util.tree_leaves(params))
 
 
-def param_bytes(params: Params) -> int:
-    return sum(x.size * x.dtype.itemsize for x in jax.tree_util.tree_leaves(params))
-
-
 def stack_layers(init_one: Callable[[jax.Array], Params], key, n: int) -> Params:
     """Initialise ``n`` identical layers as one stacked pytree (leading axis n).
 
     Stacked layouts let transformer stacks run under ``jax.lax.scan``, which
-    keeps the HLO (and XLA compile time) independent of depth -- essential for
-    the 512-device dry-runs of the 61-layer DeepSeek config.
+    keeps the HLO (and XLA compile time) independent of depth.
     """
     keys = jax.random.split(key, n)
     return jax.vmap(init_one)(keys)

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from .common import Params, conv_params, dense_params, keygen, norm_params, stack_layers
-from .layers import conv2d, dense, gelu, layernorm, softmax_xent
+from .layers import conv2d, dense, gelu, layernorm
 
 __all__ = ["ConvNeXtConfig", "init", "apply"]
 
@@ -96,8 +96,3 @@ def apply(params: Params, cfg: ConvNeXtConfig, x: jax.Array) -> jax.Array:
             x = conv2d(x, stage["down"], stride=2, padding="VALID")
     x = layernorm(jnp.mean(x, axis=(1, 2)), params["ln"])
     return dense(x, params["head"])
-
-
-def loss_fn(params, cfg: ConvNeXtConfig, images, labels):
-    logits = apply(params, cfg, images)
-    return softmax_xent(logits, labels), {"logits": logits}

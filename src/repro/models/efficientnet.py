@@ -14,15 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from .common import Params, conv_params, dense_params, keygen
-from .layers import (
-    batchnorm_inference,
-    batchnorm_train,
-    conv2d,
-    dense,
-    global_avg_pool,
-    silu,
-    softmax_xent,
-)
+from .layers import batchnorm_inference, conv2d, dense, global_avg_pool, silu
 
 __all__ = ["EfficientNetConfig", "init", "apply"]
 
@@ -92,22 +84,19 @@ def _mbconv_init(key, c_in, c_out, expand, k, se_ratio, dtype):
     return p
 
 
-def _bn(x, p, train):
-    return batchnorm_train(x, p) if train else batchnorm_inference(x, p)
-
-
-def _mbconv_apply(p, x, stride, k, train):
+def _mbconv_apply(p, x, stride, k):
     c_in = x.shape[-1]
     h = x
     if "expand" in p:
-        h = silu(_bn(conv2d(h, p["expand"], padding="VALID"), p["bn0"], train))
+        h = silu(batchnorm_inference(conv2d(h, p["expand"], padding="VALID"), p["bn0"]))
     pad = (k - 1) // 2
-    h = silu(_bn(conv2d(h, p["dw"], stride=stride, padding=pad, groups=h.shape[-1]), p["bn1"], train))
+    h = silu(batchnorm_inference(
+        conv2d(h, p["dw"], stride=stride, padding=pad, groups=h.shape[-1]), p["bn1"]))
     # squeeze-excitation (the global pool is the cross-segment sync point)
     se = global_avg_pool(h)
     se = jax.nn.sigmoid(dense(silu(dense(se, p["se_reduce"])), p["se_expand"]))
     h = h * se[:, None, None, :]
-    h = _bn(conv2d(h, p["project"], padding="VALID"), p["bn2"], train)
+    h = batchnorm_inference(conv2d(h, p["project"], padding="VALID"), p["bn2"])
     if stride == 1 and h.shape[-1] == c_in:
         h = h + x
     return h
@@ -145,14 +134,11 @@ def block_meta(cfg: EfficientNetConfig) -> list[tuple[int, int]]:
     return meta
 
 
-def apply(params: Params, cfg: EfficientNetConfig, x: jax.Array, train: bool = False) -> jax.Array:
-    x = silu(_bn(conv2d(x, params["stem"], stride=2, padding=1), params["stem_bn"], train))
+def apply(params: Params, cfg: EfficientNetConfig, x: jax.Array) -> jax.Array:
+    x = silu(batchnorm_inference(
+        conv2d(x, params["stem"], stride=2, padding=1), params["stem_bn"]))
     for p_b, (s, k) in zip(params["blocks"], block_meta(cfg)):
-        x = _mbconv_apply(p_b, x, s, k, train)
-    x = silu(_bn(conv2d(x, params["head_conv"], padding="VALID"), params["head_bn"], train))
+        x = _mbconv_apply(p_b, x, s, k)
+    x = silu(batchnorm_inference(
+        conv2d(x, params["head_conv"], padding="VALID"), params["head_bn"]))
     return dense(global_avg_pool(x), params["fc"])
-
-
-def loss_fn(params, cfg: EfficientNetConfig, images, labels):
-    logits = apply(params, cfg, images, train=True)
-    return softmax_xent(logits, labels), {"logits": logits}

@@ -48,19 +48,10 @@ def conv2d(
     return y
 
 
-def depthwise_conv2d(x, p, stride=1, padding="SAME"):
-    return conv2d(x, p, stride=stride, padding=padding, groups=x.shape[-1])
-
-
 def max_pool(x, k=2, s=2, padding="VALID"):
     return lax.reduce_window(
         x, -jnp.inf, lax.max, (1, k, k, 1), (1, s, s, 1), padding
     )
-
-
-def avg_pool(x, k=2, s=2, padding="VALID"):
-    total = lax.reduce_window(x, 0.0, lax.add, (1, k, k, 1), (1, s, s, 1), padding)
-    return total / float(k * k)
 
 
 def global_avg_pool(x):
@@ -120,13 +111,6 @@ def batchnorm_inference(x, p: Params, eps=1e-5):
     return (x - p["mean"]) * lax.rsqrt(p["var"] + eps) * p["scale"] + p["b"]
 
 
-def batchnorm_train(x, p: Params, eps=1e-5, axes=(0, 1, 2)):
-    """Batch-stats BN (no running-average update; fine for the smoke trainer)."""
-    mu = jnp.mean(x, axis=axes, keepdims=True)
-    var = jnp.var(x, axis=axes, keepdims=True)
-    return (x - mu) * lax.rsqrt(var + eps) * p["scale"] + p["b"]
-
-
 def gelu(x):
     return jax.nn.gelu(x, approximate=True)
 
@@ -137,26 +121,3 @@ def silu(x):
 
 def relu(x):
     return jax.nn.relu(x)
-
-
-def softmax_xent(logits, labels):
-    """Mean cross-entropy with integer labels.
-
-    The explicit f32 cast does two jobs: stable logsumexp, and -- because the
-    transpose of ``convert`` casts back -- it keeps the *cotangent* stream in
-    the params' (bf16) dtype.  Without it the whole backward pass runs in f32,
-    doubling activation-gradient memory traffic and every TP all-reduce
-    (EXPERIMENTS.md §Perf, qwen3 iteration 2)."""
-    logits = logits.astype(jnp.float32)
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
-    return jnp.mean(logz - gold)
-
-
-def drop_path(x, key, rate: float):
-    """Stochastic depth (per-sample residual drop)."""
-    if rate <= 0.0:
-        return x
-    shape = (x.shape[0],) + (1,) * (x.ndim - 1)
-    keep = jax.random.bernoulli(key, 1.0 - rate, shape)
-    return x * keep / (1.0 - rate)

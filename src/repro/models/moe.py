@@ -80,8 +80,7 @@ def _dispatch(ids: jax.Array, e: int, capacity: int):
     rank minus the first rank of its expert.  Identical assignment semantics
     to the GShard one-hot cumsum (stable sort preserves token-major priority)
     at ~E x lower memory traffic -- the cumsum materialises [T*k, E] and
-    prefix-scans it in log passes, which dominated the DeepSeek train-step
-    bytes in the baseline roofline (EXPERIMENTS.md §Perf iteration 1)."""
+    prefix-scans it in log passes."""
     t, k = ids.shape
     flat = ids.reshape(-1)  # [N = T*k], token-major order
     n = flat.shape[0]
@@ -107,19 +106,13 @@ def moe_apply(p: Params, cfg: MoEConfig, x: jax.Array) -> tuple[jax.Array, dict]
     flat_e, pos, keep = _dispatch(ids, e, capacity)
 
     # gather tokens into [E, C, D] slots; dropped (token, choice) pairs go to a
-    # dummy slot so kept slots have exactly one writer.  The sharding hints
-    # anchor the dispatch boundary: tokens batch-sharded in, slots
-    # expert-sharded out, so GSPMD reshards with one all-to-all instead of
-    # all-reducing [N, D] partial products (§Perf deepseek iteration 3).
-    from ..parallel.hints import constrain
-
-    x = constrain(x, "moe_tokens")
+    # dummy slot so kept slots have exactly one writer.
     dummy = e * capacity
     slot = jnp.where(keep, flat_e * capacity + pos, dummy)  # [T, k]
     token_idx = jnp.broadcast_to(jnp.arange(tkn)[:, None], (tkn, k))
     xs = jnp.zeros((e * capacity + 1, d), x.dtype)
     xs = xs.at[slot.reshape(-1)].set(x[token_idx.reshape(-1)])
-    xs = constrain(xs[:-1].reshape(e, capacity, d), "moe_slots")
+    xs = xs[:-1].reshape(e, capacity, d)
 
     w = p["experts"]
     h = jnp.einsum("ecd,edf->ecf", xs, w["w1"])
@@ -129,7 +122,7 @@ def moe_apply(p: Params, cfg: MoEConfig, x: jax.Array) -> tuple[jax.Array, dict]
     # combine back with gates (dropped choices contribute zero)
     y_pad = jnp.concatenate([y_e, jnp.zeros((1, d), y_e.dtype)], axis=0)
     picked = y_pad[slot.reshape(-1)].reshape(tkn, k, d)
-    y = constrain(jnp.sum(picked * (gates * keep)[..., None], axis=1), "moe_tokens")
+    y = jnp.sum(picked * (gates * keep)[..., None], axis=1)
 
     if cfg.n_shared:
         s = p["shared"]

@@ -26,7 +26,7 @@ import numpy as np
 from jax import lax
 
 from .common import Params, conv_params, dense_params, keygen, norm_params, stack_layers, trunc_normal
-from .layers import conv2d, dense, layernorm, softmax_xent
+from .layers import conv2d, dense, layernorm
 
 __all__ = ["SwinConfig", "init", "apply"]
 
@@ -230,8 +230,3 @@ def apply(params: Params, cfg: SwinConfig, x: jax.Array) -> jax.Array:
     with jax.named_scope("head"):
         x = _ln(x, params["ln"])
         return dense(jnp.mean(x, axis=(1, 2)), params["head"])
-
-
-def loss_fn(params, cfg: SwinConfig, images, labels):
-    logits = apply(params, cfg, images)
-    return softmax_xent(logits, labels), {"logits": logits}

@@ -6,13 +6,13 @@
 * deepseek-v3-671b    -- MLA + MoE (1 shared + 256 routed top-8) + MTP
 
 Layer stacks are *stacked pytrees* scanned with ``jax.lax.scan`` so HLO size
-and XLA compile time are depth-independent (the 512-device dry-run compiles the
-61-layer DeepSeek config in one scanned block).  ``first_k_dense`` leading
-layers (DeepSeek) form a second, smaller stack.
+and XLA compile time are depth-independent (the 61-layer DeepSeek config
+traces as one scanned block).  ``first_k_dense`` leading layers (DeepSeek)
+form a second, smaller stack.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from functools import partial
 
 import jax
@@ -30,10 +30,10 @@ from .attention import (
     mla_init,
 )
 from .common import Params, dense_params, keygen, norm_params, stack_layers, trunc_normal
-from .layers import dense, rmsnorm, silu, softmax_xent
+from .layers import dense, rmsnorm, silu
 from .moe import MoEConfig, moe_apply, moe_init
 
-__all__ = ["LMConfig", "init", "forward", "loss_fn", "decode_step", "init_cache"]
+__all__ = ["LMConfig", "init", "forward", "decode_step", "init_cache"]
 
 
 @dataclass(frozen=True)
@@ -52,7 +52,7 @@ class LMConfig:
     mla: MLAConfig | None = None
     moe: MoEConfig | None = None
     first_k_dense: int = 0
-    mtp_depth: int = 0  # DeepSeek multi-token prediction heads
+    mtp_depth: int = 0  # DeepSeek MTP modules: in the params, read by no forward
     remat: bool = True
 
     @property
@@ -174,14 +174,11 @@ def _block_apply(
 
 
 def _scan_stack(p_stack, cfg, moe_layer, x, positions, mask):
-    from ..parallel.hints import constrain
-
     blk = partial(_block_apply, cfg=cfg, moe_layer=moe_layer)
 
     def body(carry, p_l):
         x, lb = carry
         x, _, lb_l = blk(p_l, x=x, positions=positions, mask=mask)
-        x = constrain(x, "lm_residual")
         return (x, lb + lb_l), None
 
     if cfg.remat:
@@ -212,43 +209,6 @@ def forward(params: Params, cfg: LMConfig, tokens: jax.Array) -> tuple[jax.Array
     x = rmsnorm(x, params["final_norm"])
     logits = dense(x, params["lm_head"])
     return logits, lb
-
-
-def loss_fn(
-    params: Params,
-    cfg: LMConfig,
-    tokens: jax.Array,
-    labels: jax.Array,
-    lb_coef: float = 0.01,
-    mtp_coef: float = 0.3,
-) -> tuple[jax.Array, dict]:
-    h, lb = trunk(params, cfg, tokens)
-    logits = dense(rmsnorm(h, params["final_norm"]), params["lm_head"])
-    loss = softmax_xent(logits, labels)
-    metrics = {"ce": loss, "load_balance": lb}
-    if cfg.mtp_depth and "mtp" in params:
-        # DeepSeek-V3 MTP (depth 1): predict token t+2 from the trunk state at
-        # t and the embedding of the label at t+1 (shares embed + lm_head).
-        b, t = tokens.shape
-        positions = jnp.broadcast_to(jnp.arange(t), (b, t))
-        mask = causal_mask(t)
-        mtp = jax.tree_util.tree_map(lambda a: a[0], params["mtp"])  # depth-1 module
-        emb_next = params["embed"][labels]  # embedding of token t+1
-        merged = dense(
-            jnp.concatenate([rmsnorm(h, mtp["norm_h"]), rmsnorm(emb_next, mtp["norm_e"])], -1),
-            mtp["proj"],
-        )
-        h2, _, _ = _block_apply(
-            mtp["block"], cfg, bool(cfg.moe), merged, positions, mask
-        )
-        logits2 = dense(rmsnorm(h2, params["final_norm"]), params["lm_head"])
-        # labels for t+2: shift `labels` left by one (drop the last column)
-        mtp_loss = softmax_xent(logits2[:, :-1], labels[:, 1:])
-        metrics["mtp"] = mtp_loss
-        loss = loss + mtp_coef * mtp_loss
-    loss = loss + lb_coef * lb
-    metrics["total"] = loss
-    return loss, metrics
 
 
 # ---------------------------------------------------------------------------

@@ -32,7 +32,6 @@ class UNetConfig:
     ctx_len: int = 77
     n_heads: int = 8
     groups: int = 32
-    attn_f32: bool = True  # f32 softmax (training); serving uses bf16 (SD-style fp16 inference)
 
     @property
     def latent_res(self) -> int:
@@ -86,17 +85,14 @@ def _attn_init(key, c, ctx_dim, dtype):
     }
 
 
-def _mha(q, k, v, heads, f32=True):
+def _mha(q, k, v, heads):
     b, n, c = q.shape
     m = k.shape[1]
     q = q.reshape(b, n, heads, c // heads)
     k = k.reshape(b, m, heads, c // heads)
     v = v.reshape(b, m, heads, c // heads)
     logits = jnp.einsum("bnhd,bmhd->bhnm", q, k) / jnp.sqrt(c / heads)
-    if f32:
-        probs = jax.nn.softmax(logits.astype(jnp.float32), -1).astype(q.dtype)
-    else:  # serving: keep the softmax chain in bf16 (halves HBM boundary bytes)
-        probs = jax.nn.softmax(logits, -1)
+    probs = jax.nn.softmax(logits.astype(jnp.float32), -1).astype(q.dtype)
     return jnp.einsum("bhnm,bmhd->bnhd", probs, v).reshape(b, n, c)
 
 
@@ -106,17 +102,17 @@ def _ln(x, p, eps=1e-5):
     return (x - mu) * jax.lax.rsqrt(var + eps) * p["scale"] + p["b"]
 
 
-def _attn_apply(p, x, ctx, heads, groups, f32=True):
+def _attn_apply(p, x, ctx, heads, groups):
     b, h, w, c = x.shape
     res = x
     xn = groupnorm(x, p["norm"], groups)
     t = dense(xn.reshape(b, h * w, c), p["proj_in"])
     # self
     tn = _ln(t, p["n1"])
-    t = t + dense(_mha(dense(tn, p["sq"]), dense(tn, p["sk"]), dense(tn, p["sv"]), heads, f32), p["so"])
+    t = t + dense(_mha(dense(tn, p["sq"]), dense(tn, p["sk"]), dense(tn, p["sv"]), heads), p["so"])
     # cross
     tn = _ln(t, p["n2"])
-    t = t + dense(_mha(dense(tn, p["cq"]), dense(ctx, p["ck"]), dense(ctx, p["cv"]), heads, f32), p["co"])
+    t = t + dense(_mha(dense(tn, p["cq"]), dense(ctx, p["ck"]), dense(ctx, p["cv"]), heads), p["co"])
     # geglu
     tn = _ln(t, p["n3"])
     u = dense(tn, p["ff1"])
@@ -192,21 +188,21 @@ def apply(params: Params, cfg: UNetConfig, x, t, ctx) -> jax.Array:
         for p_res, p_attn in zip(level["res"], level["attn"]):
             h = _res_apply(p_res, h, temb, cfg.groups)
             if p_attn:
-                h = _attn_apply(p_attn, h, ctx, cfg.n_heads, cfg.groups, cfg.attn_f32)
+                h = _attn_apply(p_attn, h, ctx, cfg.n_heads, cfg.groups)
             skips.append(h)
         if "downsample" in level:
             h = conv2d(h, level["downsample"], stride=2, padding=1)
             skips.append(h)
     m = params["mid"]
     h = _res_apply(m["res1"], h, temb, cfg.groups)
-    h = _attn_apply(m["attn"], h, ctx, cfg.n_heads, cfg.groups, cfg.attn_f32)
+    h = _attn_apply(m["attn"], h, ctx, cfg.n_heads, cfg.groups)
     h = _res_apply(m["res2"], h, temb, cfg.groups)
     for li, level in enumerate(params["up"]):
         for p_res, p_attn in zip(level["res"], level["attn"]):
             h = jnp.concatenate([h, skips.pop()], axis=-1)
             h = _res_apply(p_res, h, temb, cfg.groups)
             if p_attn:
-                h = _attn_apply(p_attn, h, ctx, cfg.n_heads, cfg.groups, cfg.attn_f32)
+                h = _attn_apply(p_attn, h, ctx, cfg.n_heads, cfg.groups)
         if "upsample" in level:
             b, hh, ww, c = h.shape
             h = jax.image.resize(h, (b, hh * 2, ww * 2, c), "nearest")

@@ -8,7 +8,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from .common import Params, conv_params, dense_params, keygen, norm_params, stack_layers, trunc_normal
-from .layers import conv2d, dense, gelu, layernorm, softmax_xent
+from .layers import conv2d, dense, gelu, layernorm
 
 __all__ = ["ViTConfig", "init", "apply", "vit_block_init", "vit_block_apply"]
 
@@ -93,8 +93,3 @@ def apply(params: Params, cfg: ViTConfig, x: jax.Array) -> jax.Array:
     with jax.named_scope("head"):
         x = layernorm(x, params["ln"])
         return dense(x[:, 0], params["head"])
-
-
-def loss_fn(params, cfg: ViTConfig, images, labels):
-    logits = apply(params, cfg, images)
-    return softmax_xent(logits, labels), {"logits": logits}

@@ -1,8 +1,1 @@
-from .sharding import (
-    input_shardings,
-    param_shardings,
-    shard_rules,
-    spatial_shardings,
-    state_shardings,
-    weighted_spatial_inputs,
-)
+from .sharding import spatial_shardings, weighted_spatial_inputs

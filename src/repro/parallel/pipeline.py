@@ -3,14 +3,12 @@
 Each device along ``axis_name`` owns one *stage* (a slice of the layer stack);
 microbatches stream through the ring with ``ppermute`` between stages.  With S
 stages and M microbatches the schedule runs S + M - 1 ticks; bubble fraction
-(S-1)/(S+M-1).  Designed for the ``pod`` axis of the production mesh (2
-stages across pods, DP×TP inside each pod) where inter-pod links are the
-scarce resource — the paper's principle again: only the thin activation
-boundary crosses the slow link, and it crosses while both pods compute.
+(S-1)/(S+M-1).  Designed for a mesh axis whose links are the scarce
+resource -- the paper's principle again: only the thin activation boundary
+crosses the slow link, and it crosses while both sides compute.
 
 The implementation is deliberately simple (no interleaving/looping schedule);
-it composes with the TP/FSDP rules because the stage body is an arbitrary
-jax function.
+the stage body is an arbitrary jax function.
 """
 from __future__ import annotations
 

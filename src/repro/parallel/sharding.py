@@ -1,246 +1,14 @@
-"""Parameter / input sharding rules (DP + TP + EP + SP + optional FSDP).
-
-Rules are (path-regex, spec) pairs matched against ``a/b/c`` pytree paths;
-specs are axis-name tuples where the special token ``"fsdp"`` resolves to the
-mesh's data axes (ZeRO-3 parameter sharding, enabled for the large LM configs)
-and may silently drop to replication when a dimension is not divisible.
-Stacked layer pytrees (leading scan axis) are handled by left-padding specs
-with None when the leaf rank exceeds the spec rank.
-
-Input sharding is per (family, cell-kind) with two deliberate SP cases -- the
-paper's technique deployed through GSPMD:
-
-* vision ``serve_b1``       -- image *height* sharded (spatial partitioning;
-  XLA inserts the exact halo exchanges the rf-arithmetic prescribes),
-* diffusion ``gen_1024``    -- latent height sharded across ``data``.
+"""Height shardings for the HALP spatial executor (``repro.spatial``): the
+image batch is split along its height over one mesh axis and the parameters
+are replicated, for the equal split and the capacity-weighted padded layout
+alike.
 """
 from __future__ import annotations
 
-import re
-from typing import Any, Sequence
-
 import jax
-import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..configs.base import Arch, Cell
-from ..launch.mesh import dp_axes, fsdp_axes
-
-__all__ = [
-    "param_shardings",
-    "input_shardings",
-    "state_shardings",
-    "shard_rules",
-    "spatial_shardings",
-    "weighted_spatial_inputs",
-]
-
-M = "model"
-
-
-def _lm_rules(big: bool):
-    from .variants import get_variant
-
-    v = get_variant()
-    big = big or v.lm_fsdp_small
-    fs = "fsdp" if big else None
-    embed = (M, fs) if v.embed_vocab_shard else (fs, M)
-    head = (None, None) if v.replicate_lm_head else (fs, M)
-    if v.gather_experts:
-        return [
-            (r"embed$", embed),
-            (r"lm_head/w$", head),
-            (r"(wq|wk|wv|wqkv)/w$", (fs, M)),
-            (r"wo/w$", (M, fs)),
-            (r"(wdq|wuq|wdkv|wukv|wkr)/w$", (fs, M)),
-            (r"ffn/(w1|w3)/w$", (fs, M)),
-            (r"ffn/w2/w$", (M, fs)),
-            (r"experts/", (None, None, None)),
-            (r"router/w$", (None, None)),
-        ]
-    return [
-        (r"embed$", embed),
-        (r"lm_head/w$", head),
-        (r"(wq|wk|wv|wqkv)/w$", (fs, M)),
-        (r"wo/w$", (M, fs)),
-        (r"(wdq|wuq|wdkv|wukv|wkr)/w$", (fs, M)),
-        (r"ffn/(w1|w3)/w$", (fs, M)),
-        (r"ffn/w2/w$", (M, fs)),
-        (r"shared/(w1|w3)/w$", (fs, M)),
-        (r"shared/w2/w$", (M, fs)),
-        (r"experts/(w1|w3)$", (M, "fsdp" if big else None, None)),
-        (r"experts/w2$", (M, None, "fsdp" if big else None)),
-        (r"mtp/proj/w$", (fs, M)),
-        (r"router/w$", (None, None)),
-    ]
-
-
-def _vision_rules():
-    return [
-        (r"(patch_embed|stem)/w$", (None, None, None, M)),
-        (r"(wqkv|fc1|pw1)/w$", (None, M)),
-        (r"(wo|fc2|pw2)/w$", (M, None)),
-        (r"head/w$", (None, M)),
-        (r"merge/w$", (None, M)),
-        (r"(expand|project|head_conv|dw|down)/w$", (None, None, None, M)),
-        (r"(se_reduce|se_expand|fc)/w$", (None, M)),
-    ]
-
-
-def _diffusion_rules():
-    return [
-        (r"(fc1|ff1|wqkv|sq|sk|sv|cq|ck|cv|t_mlp1|t1|proj_in)/w$", (None, M)),
-        (r"(fc2|ff2|wo|so|co|t_mlp2|t2|proj_out)/w$", (M, None)),
-        (r"ada/w$", (None, M)),
-        (r"final_ada/w$", (None, M)),
-        (r"(c1|c2|conv_in|conv_out|downsample|upsample|skip)/w$", (None, None, None, M)),
-        (r"temb/w$", (None, M)),
-        (r"(patch_embed)/w$", (None, None, None, M)),
-        (r"final/w$", (None, M)),
-        (r"label_embed$", (None, M)),
-    ]
-
-
-def shard_rules(arch: Arch):
-    from .variants import get_variant
-
-    if arch.family == "lm":
-        big = arch.name.startswith(("deepseek", "moonshot"))
-        return _lm_rules(big)
-    if arch.family == "vision":
-        return _vision_rules()
-    if arch.family == "diffusion":
-        if get_variant().diffusion_spatial2d:
-            return []  # replicate params; parallelism is purely spatial
-        return _diffusion_rules()
-    return []
-
-
-def _path_str(path) -> str:
-    parts = []
-    for p in path:
-        if hasattr(p, "key"):
-            parts.append(str(p.key))
-        elif hasattr(p, "idx"):
-            parts.append(str(p.idx))
-        else:
-            parts.append(str(p))
-    return "/".join(parts)
-
-
-def _axis_size(mesh: Mesh, token) -> int:
-    if token is None:
-        return 1
-    if isinstance(token, tuple):
-        return int(np.prod([mesh.shape[a] for a in token]))
-    return mesh.shape[token]
-
-
-def _resolve(spec_tokens, mesh: Mesh, shape) -> P:
-    """Map rule tokens onto the mesh, dropping non-divisible entries, and
-    left-pad with None for stacked (scan) leading axes."""
-    fs = fsdp_axes(mesh)
-    tokens = []
-    for t in spec_tokens:
-        if t == "fsdp":
-            t = fs if len(fs) > 1 else fs[0]
-        tokens.append(t)
-    if len(tokens) < len(shape):
-        tokens = [None] * (len(shape) - len(tokens)) + tokens
-    tokens = tokens[: len(shape)]
-    out = []
-    for dim, t in zip(shape, tokens):
-        if t is not None and dim % _axis_size(mesh, t) == 0:
-            out.append(t)
-        else:
-            out.append(None)
-    return P(*out)
-
-
-def param_shardings(abstract_params, arch: Arch, mesh: Mesh):
-    rules = [(re.compile(rx), spec) for rx, spec in shard_rules(arch)]
-
-    def assign(path, leaf):
-        ps = _path_str(path)
-        for rx, spec in rules:
-            if rx.search(ps):
-                return NamedSharding(mesh, _resolve(spec, mesh, leaf.shape))
-        return NamedSharding(mesh, P())
-
-    return jax.tree_util.tree_map_with_path(assign, abstract_params)
-
-
-def state_shardings(abstract_state, arch: Arch, mesh: Mesh):
-    """(params, opt, step): moments follow the parameter sharding."""
-    params_abs, opt_abs, _ = abstract_state
-    p_sh = param_shardings(params_abs, arch, mesh)
-    mu_sh = jax.tree_util.tree_map(
-        lambda s, a: s, p_sh, opt_abs["mu"]
-    )  # same tree structure
-    opt_sh = {"mu": mu_sh, "nu": mu_sh, "count": NamedSharding(mesh, P())}
-    return (p_sh, opt_sh, NamedSharding(mesh, P()))
-
-
-def _dp(mesh) -> Any:
-    d = dp_axes(mesh)
-    return d if len(d) > 1 else d[0]
-
-
-def input_shardings(bundle_inputs, arch: Arch, cell: Cell, mesh: Mesh):
-    """Per-input NamedShardings for one (arch, cell) bundle."""
-    dp = _dp(mesh)
-    multi = "pod" in mesh.axis_names
-    out = {}
-    for name, spec in bundle_inputs.items():
-        if name == "images" and "sp" in mesh.axis_names:
-            # dedicated spatial mesh (make_spatial_mesh): height over "sp"
-            sh = NamedSharding(mesh, P(None, "sp", None, None))
-        elif name in ("tokens", "labels") and arch.family == "lm":
-            b = spec.shape[0]
-            tok = dp if b % _axis_size(mesh, dp) == 0 else "data"
-            sh = NamedSharding(mesh, P(tok, *([None] * (len(spec.shape) - 1))))
-        elif name == "cache":
-            sh = jax.tree_util.tree_map(
-                lambda s: NamedSharding(mesh, _cache_spec(s.shape, mesh)), spec
-            )
-        elif name == "index":
-            sh = NamedSharding(mesh, P())
-        elif name in ("images",):
-            b, r = spec.shape[0], spec.shape[1]
-            if b == 1:  # serve_b1: the paper's SP -- shard the height axis
-                ax = dp if r % _axis_size(mesh, dp) == 0 else "data"
-                sh = NamedSharding(mesh, P(None, ax, None, None))
-            elif b % _axis_size(mesh, dp) == 0:
-                sh = NamedSharding(mesh, P(dp, None, None, None))
-            else:
-                sh = NamedSharding(mesh, P("data", None, None, None))
-        elif name in ("latents", "noise"):
-            from .variants import get_variant
-
-            b, r = spec.shape[0], spec.shape[1]
-            if get_variant().diffusion_spatial2d and cell.kind == "gen":
-                # the paper's technique in 2-D: H over data, W over model
-                sh = NamedSharding(mesh, P(None, "data", "model", None))
-            elif b % _axis_size(mesh, dp) == 0:
-                sh = NamedSharding(mesh, P(dp, None, None, None))
-            elif multi and b % mesh.shape["pod"] == 0 and r % mesh.shape["data"] == 0:
-                sh = NamedSharding(mesh, P("pod", "data", None, None))
-            elif b % mesh.shape["data"] == 0:
-                sh = NamedSharding(mesh, P("data", None, None, None))
-            else:  # small-batch gen: spatial sharding of the latent height
-                sh = NamedSharding(mesh, P(None, "data", None, None))
-        elif name in ("t", "cond", "ctx"):
-            b = spec.shape[0]
-            ax = dp if b % _axis_size(mesh, dp) == 0 else ("data" if b % mesh.shape["data"] == 0 else None)
-            sh = NamedSharding(mesh, P(ax, *([None] * (len(spec.shape) - 1))))
-        else:
-            b = spec.shape[0] if spec.shape else None
-            ax = dp if b and b % _axis_size(mesh, dp) == 0 else None
-            sh = NamedSharding(
-                mesh, P(ax, *([None] * (max(0, len(spec.shape) - 1)))) if spec.shape else P()
-            )
-        out[name] = sh
-    return out
+__all__ = ["spatial_shardings", "weighted_spatial_inputs"]
 
 
 def spatial_shardings(mesh: Mesh, *, axis: str = "sp"):
@@ -276,25 +44,3 @@ def weighted_spatial_inputs(x, plan_or_heights, mesh: Mesh, *, axis: str = "sp",
     xp = to_padded_shards(x, heights)
     act_sh, _ = spatial_shardings(mesh, axis=axis)
     return jax.device_put(xp, act_sh), heights
-
-
-def _cache_spec(shape, mesh: Mesh) -> P:
-    """KV caches: [L, B, S, H, dh] -> batch over data, SEQUENCE over model.
-
-    Sequence sharding gives distributed-softmax decode attention: per-shard
-    logits stay local, the softmax reduces via tiny psums, and the weighted
-    value sum all-reduces one [B, 1, H, dh] vector per layer.  (Head-dim
-    sharding -- the first design -- made GSPMD all-gather the whole cache
-    shard every step: +40 GB/step on qwen3 decode; §Perf decode iteration.)
-    MLA latent caches: [L, B, S, R] -> same layout."""
-    dp = _dp(mesh)
-    b = shape[1]
-    bt = dp if b % _axis_size(mesh, dp) == 0 else (
-        "data" if b % mesh.shape["data"] == 0 else None
-    )
-    s_ax = M if shape[2] % mesh.shape[M] == 0 else None
-    if len(shape) == 5:
-        return P(None, bt, s_ax, None, None)
-    if len(shape) == 4:
-        return P(None, bt, s_ax, None)
-    return P()

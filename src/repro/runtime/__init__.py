@@ -1,5 +1,3 @@
-from .checkpoint import latest_step, restore_checkpoint, save_checkpoint
-from .fault import FaultConfig, FaultTolerantTrainer, InjectedFault
 from .serve import (
     BatchingEngine,
     Request,

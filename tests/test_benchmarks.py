@@ -306,19 +306,3 @@ def test_scheme_bench_artifact_floors():
         assert cell["halo_only"]["comm_bytes_per_stage"]
         assert cell["searched"]["comm_bytes_per_stage"]
     assert out["max_reduction"] >= 0.10, out["max_reduction"]
-
-
-def test_roofline_results_complete():
-    """Dry-run artifacts exist for all 40 cells x both meshes (ok or recorded
-    skip), i.e. deliverables (e)/(g) are materialised."""
-    from benchmarks import roofline
-
-    for mesh in ("pod16x16", "pod2x16x16"):
-        recs = roofline.load_all(mesh)
-        if not recs:
-            pytest.skip(f"dry-run not yet executed for {mesh}")
-        assert len(recs) == 40, (mesh, len(recs))
-        bad = [r for r in recs if r["status"] not in ("ok", "skipped")]
-        assert not bad, [(r["arch"], r["cell"], r.get("error", "")[:60]) for r in bad]
-        skips = [r for r in recs if r["status"] == "skipped"]
-        assert len(skips) == 4  # long_500k x 4 full-attention LMs

@@ -1,4 +1,4 @@
-"""Property tests on the scheduling core + config registry invariants."""
+"""Property tests on the scheduling core and the pipeline schedule."""
 import pytest
 from hypothesis import given, settings, strategies as st
 
@@ -62,38 +62,3 @@ def test_bubble_fraction():
     assert bubble_fraction(2, 1) == pytest.approx(0.5)
     assert bubble_fraction(2, 14) == pytest.approx(1 / 15)
     assert bubble_fraction(8, 56) == pytest.approx(7 / 63)
-
-
-def test_registry_cells_total_40():
-    """The assigned pool: 10 archs x 4 shapes = 40 cells (+ vgg16 extra, and
-    swin-b-384, a served configuration with no assigned cells)."""
-    from repro.configs import get, list_archs
-
-    assert get("swin-b-384").cells == {}
-    assigned = [a for a in list_archs() if a != "vgg16" and get(a).cells]
-    assert len(assigned) == 10
-    total = sum(len(get(a).cells) for a in assigned)
-    assert total == 40
-    # every skip is recorded with a reason
-    for a in assigned:
-        for c in get(a).cells.values():
-            if c.skip:
-                assert "sub-quadratic" in c.skip
-
-
-def test_dryrun_artifacts_have_corrected_costs():
-    """All ok dry-run records carry the while-trip-corrected hlo_cost."""
-    import json
-    from pathlib import Path
-
-    results = Path(__file__).resolve().parents[1] / "benchmarks" / "dryrun_results"
-    if not results.exists():
-        pytest.skip("dry-run not executed")
-    n = 0
-    for f in results.glob("*__pod16x16.json"):
-        rec = json.loads(f.read_text())
-        if rec["status"] == "ok":
-            assert "hlo_cost" in rec, f.name
-            assert rec["hlo_cost"]["flops"] > 0, f.name
-            n += 1
-    assert n >= 36

@@ -16,7 +16,7 @@ from repro.models.attention import (
     gqa_init,
 )
 from repro.models.common import norm_params
-from repro.models.layers import layernorm, rmsnorm, softmax_xent
+from repro.models.layers import layernorm, rmsnorm
 from repro.models.moe import MoEConfig, moe_apply, moe_init, router_topk
 
 
@@ -159,14 +159,6 @@ def test_rmsnorm_scale_invariant(d):
     y1 = rmsnorm(x, p)
     y2 = rmsnorm(7.5 * x, p)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), rtol=2e-4, atol=1e-5)
-
-
-def test_softmax_xent_matches_manual():
-    logits = jax.random.normal(jax.random.PRNGKey(0), (4, 7))
-    labels = jnp.array([1, 0, 6, 3])
-    want = -jnp.mean(jax.nn.log_softmax(logits)[jnp.arange(4), labels])
-    got = softmax_xent(logits, labels)
-    assert float(got) == pytest.approx(float(want), rel=1e-6)
 
 
 # ---------------------------------------------------------------------------

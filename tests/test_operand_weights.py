@@ -22,7 +22,8 @@ def _key(path) -> str | None:
     return getattr(path[-1], "key", None)
 
 
-@pytest.mark.parametrize("arch", ["vgg16", "vit-l16", "swin-b-384"])
+@pytest.mark.parametrize(
+    "arch", ["vgg16", "vit-l16", "swin-b-384", "convnext-b", "efficientnet-b7", "swin-b"])
 def test_kernels_are_kept_in_bf16_and_the_rest_in_f32(arch):
     log = SpanLog()
     _cfg, params, _fn = build_model(arch, smoke=True, spans=log)
@@ -84,6 +85,8 @@ def _elem(tensor_type: str) -> str:
 @pytest.mark.parametrize("arch, f32_products", [
     ("vgg16", 0),
     ("vit-l16", 2),  # attention's scores and values multiply activations, which stay f32
+    ("convnext-b", 0),
+    ("efficientnet-b7", 0),
 ])
 def test_served_program_converts_no_weight_on_the_call(arch, f32_products):
     """In the served function's lowered program no convert starts from a bf16

@@ -1,247 +1,12 @@
-"""Runtime tests: checkpoint/restore, fault-tolerant training, serving engine,
-optimizer, data determinism."""
+"""Runtime tests: the serving engine and its batch-size admission policy."""
 import dataclasses
-import tempfile
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
-from repro.data import ImageStream, TokenStream
-from repro.optim import AdamWConfig, adamw_init, adamw_update, warmup_cosine
-from repro.optim.grad_compress import compress_bf16, topk_sparsify
-from repro.runtime import (
-    BatchingEngine,
-    FaultConfig,
-    FaultTolerantTrainer,
-    InjectedFault,
-    Request,
-    ServeConfig,
-    choose_batch_size,
-    latest_step,
-    restore_checkpoint,
-    save_checkpoint,
-)
+from repro.runtime import BatchingEngine, Request, ServeConfig, choose_batch_size
 from repro.core.reliability import OffloadChannel, service_reliability
-
-
-def test_adamw_reduces_quadratic():
-    cfg = AdamWConfig(lr=0.1, weight_decay=0.0, grad_clip=0.0)
-    params = {"w": jnp.array([3.0, -2.0])}
-    state = adamw_init(params, cfg)
-    for _ in range(300):
-        grads = {"w": 2 * params["w"]}
-        params, state = adamw_update(grads, state, params, cfg)
-    assert float(jnp.abs(params["w"]).max()) < 1e-2
-
-
-def test_adamw_bf16_moments():
-    cfg = AdamWConfig(moment_dtype=jnp.bfloat16)
-    params = {"w": jnp.ones((4, 4))}
-    state = adamw_init(params, cfg)
-    assert state["mu"]["w"].dtype == jnp.bfloat16
-    params2, state2 = adamw_update({"w": jnp.ones((4, 4))}, state, params, cfg)
-    assert params2["w"].dtype == params["w"].dtype
-    assert bool(jnp.isfinite(params2["w"]).all())
-
-
-def test_warmup_cosine_monotone_warmup():
-    assert float(warmup_cosine(0)) == 0.0
-    assert float(warmup_cosine(500, warmup=1000)) == pytest.approx(0.5)
-    assert float(warmup_cosine(1000)) == pytest.approx(1.0, abs=1e-3)
-
-
-def test_grad_compress():
-    g = {"a": jnp.arange(8192.0).reshape(64, 128)}
-    c = compress_bf16(g)
-    assert c["a"].dtype == jnp.bfloat16
-    s = topk_sparsify(g["a"], frac=0.1)
-    nz = float(jnp.count_nonzero(s)) / s.size
-    assert 0.05 < nz < 0.15
-
-
-def test_checkpoint_roundtrip():
-    tree = {
-        "a": jnp.arange(6.0).reshape(2, 3),
-        "b": {"c": jnp.ones((4,), jnp.bfloat16), "d": jnp.array(7, jnp.int32)},
-    }
-    with tempfile.TemporaryDirectory() as d:
-        save_checkpoint(d, 3, tree, extra={"arch": "x"})
-        save_checkpoint(d, 7, tree)
-        assert latest_step(d) == 7
-        like = jax.tree_util.tree_map(lambda x: jnp.zeros_like(x), tree)
-        restored, step, extra = restore_checkpoint(d, like, step=3)
-        assert step == 3 and extra == {"arch": "x"}
-        for a, b in zip(jax.tree_util.tree_leaves(restored), jax.tree_util.tree_leaves(tree)):
-            assert a.dtype == b.dtype
-            np.testing.assert_array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32))
-
-
-def test_data_streams_deterministic():
-    s1 = TokenStream(vocab=100, batch=2, seq_len=8, seed=1)
-    s2 = TokenStream(vocab=100, batch=2, seq_len=8, seed=1)
-    np.testing.assert_array_equal(s1.batch_at(5)["tokens"], s2.batch_at(5)["tokens"])
-    assert not np.array_equal(s1.batch_at(5)["tokens"], s1.batch_at(6)["tokens"])
-    i1 = ImageStream(img_res=8, batch=2, num_classes=4, seed=2)
-    np.testing.assert_array_equal(i1.batch_at(0)["images"], i1.batch_at(0)["images"])
-
-
-def test_fault_tolerant_trainer_recovers():
-    """Inject a failure mid-run; the trainer restores from the checkpoint and
-    converges to the same final state as an uninterrupted run."""
-    from repro.runtime.train import make_trainer
-
-    with tempfile.TemporaryDirectory() as d1, tempfile.TemporaryDirectory() as d2:
-        # uninterrupted reference
-        t_ref, s_ref = make_trainer(
-            "qwen3-4b", "train_4k", fault_cfg=FaultConfig(ckpt_dir=d1, ckpt_every=2)
-        )
-        s_ref, stats_ref = t_ref.run(s_ref, 6, resume=False)
-
-        # faulting run: blows up at step 3, twice
-        boom = {"n": 0}
-
-        def hook(i):
-            if i == 3 and boom["n"] < 2:
-                boom["n"] += 1
-                raise InjectedFault(f"chaos at step {i}")
-
-        t2, s2 = make_trainer(
-            "qwen3-4b", "train_4k",
-            fault_cfg=FaultConfig(ckpt_dir=d2, ckpt_every=2),
-            fault_hook=hook,
-        )
-        s2, stats = t2.run(s2, 6, resume=False)
-        assert stats.failures == 2 and stats.restores >= 2
-        # deterministic stream + checkpoint replay => identical final params
-        p_ref, p2 = s_ref[0], s2[0]
-        for a, b in zip(jax.tree_util.tree_leaves(p_ref), jax.tree_util.tree_leaves(p2)):
-            np.testing.assert_allclose(
-                np.asarray(a, np.float32), np.asarray(b, np.float32), rtol=1e-5, atol=1e-6
-            )
-        assert int(s2[2]) == 6  # step counter advanced to completion
-
-
-class _CountStream:
-    """Deterministic toy stream: batch i carries the scalar i."""
-
-    def batch_at(self, i):
-        return {"x": jnp.asarray(float(i))}
-
-
-def _toy_trainer(ckpt_dir, max_failures=3, fault_hook=None, **kw):
-    def step(state, x):
-        return {"w": state["w"] + x}, {"loss": x}
-
-    cfg = FaultConfig(ckpt_dir=ckpt_dir, ckpt_every=2, max_failures=max_failures)
-    return FaultTolerantTrainer(step, _CountStream(), cfg, fault_hook=fault_hook, **kw)
-
-
-def _fault_once_at(steps):
-    fired = set()
-
-    def hook(i):
-        if i in steps and i not in fired:
-            fired.add(i)
-            raise InjectedFault(f"chaos at step {i}")
-
-    return hook
-
-
-def test_trainer_stats_dedupe_replayed_steps():
-    """Steps replayed after a checkpoint restore must not be re-counted: a
-    fault at step 3 (ckpt at 2) replays step 2, which historically double-fed
-    steps/losses/EMA for every replayed step."""
-    with tempfile.TemporaryDirectory() as d:
-        t = _toy_trainer(d, fault_hook=_fault_once_at({3}))
-        state, stats = t.run({"w": jnp.zeros(())}, 6, resume=False)
-        assert stats.failures == 1 and stats.restores == 1
-        assert stats.steps == 6  # not 7: the replayed step 2 counts once
-        assert stats.losses == [0.0, 1.0, 2.0, 3.0, 4.0, 5.0]
-        assert float(state["w"]) == sum(range(6))  # replay itself is correct
-
-
-def test_trainer_retry_budget_is_consecutive_not_total():
-    """max_failures bounds *consecutive unrecovered* failures: a long run with
-    sparse transient faults (more total faults than the budget, but recovered
-    progress in between) must complete.  The historical counter never reset,
-    so it raised on the (max_failures+1)-th fault of the whole run."""
-    with tempfile.TemporaryDirectory() as d:
-        t = _toy_trainer(d, max_failures=1, fault_hook=_fault_once_at({1, 3, 5}))
-        state, stats = t.run({"w": jnp.zeros(())}, 8, resume=False)
-        assert stats.failures == 3  # the stats keep counting the total
-        assert stats.steps == 8 and float(state["w"]) == sum(range(8))
-
-
-def test_trainer_consecutive_failures_still_bounded():
-    """A genuinely stuck step (faulting every attempt) must still raise."""
-
-    def always_boom(i):
-        if i == 3:
-            raise InjectedFault("hard fault at step 3")
-
-    with tempfile.TemporaryDirectory() as d:
-        t = _toy_trainer(d, max_failures=2, fault_hook=always_boom)
-        with pytest.raises(RuntimeError, match="consecutive"):
-            t.run({"w": jnp.zeros(())}, 6, resume=False)
-
-
-def test_trainer_recovery_before_first_checkpoint_rewinds_state():
-    """A fault before any checkpoint exists must rewind the *state* together
-    with the step index: rewinding only the index re-applies already-consumed
-    batches to an already-advanced state (and the stats dedupe would make
-    that corruption silent)."""
-
-    class _OneBasedStream:
-        def batch_at(self, i):
-            return {"x": jnp.asarray(float(i + 1))}  # nonzero first batch
-
-    def step(state, x):
-        return {"w": state["w"] + x}, {"loss": x}
-
-    with tempfile.TemporaryDirectory() as d:
-        cfg = FaultConfig(ckpt_dir=d, ckpt_every=10, max_failures=3)  # no ckpt fits
-        t = FaultTolerantTrainer(
-            step, _OneBasedStream(), cfg, fault_hook=_fault_once_at({1})
-        )
-        state, stats = t.run({"w": jnp.zeros(())}, 4, resume=False)
-        assert stats.failures == 1
-        # batches 1..4 applied exactly once: 10, not 11 (batch 1 twice)
-        assert float(state["w"]) == 10.0
-        assert stats.steps == 4 and stats.losses == [1.0, 2.0, 3.0, 4.0]
-
-
-def test_trainer_recovery_ignores_stale_checkpoints_from_prior_runs():
-    """A fresh resume=False run recovering from a transient fault must not
-    restore a checkpoint a *previous* run left in the same ckpt_dir: that
-    would jump it to foreign state/progress (possibly past its own n_steps).
-    Only checkpoints within this run's own [start_step, high_water] qualify;
-    otherwise the run replays from its entry state."""
-    with tempfile.TemporaryDirectory() as d:
-        t1 = _toy_trainer(d)
-        t1.run({"w": jnp.zeros(())}, 8, resume=False)  # leaves step_8 etc.
-        t2 = _toy_trainer(d, fault_hook=_fault_once_at({1}))
-        state, stats = t2.run({"w": jnp.zeros(())}, 4, resume=False)
-        assert stats.failures == 1
-        assert stats.steps == 4  # ran its own 4 steps, not run 1's leftovers
-        assert float(state["w"]) == sum(range(4))  # 0+1+2+3, from entry state
-
-
-def test_trainer_stats_count_fresh_reruns():
-    """The replay-dedupe watermark must not leak across runs: a second
-    resume=False run on the same trainer re-executes from step 0 for real,
-    so its steps count (and reach the compute observer) again."""
-    seen = []
-    with tempfile.TemporaryDirectory() as d:
-        t = _toy_trainer(
-            d, compute_observer=lambda es, fl, dt: seen.append(es),
-            step_flops=1e9,
-        )
-        t.run({"w": jnp.zeros(())}, 4, resume=False)
-        assert t.stats.steps == 4 and len(seen) == 4
-        t.run({"w": jnp.zeros(())}, 4, resume=False)  # fresh run, same trainer
-        assert t.stats.steps == 8 and len(seen) == 8
 
 
 def test_serve_config_rejects_shed_as_max_batch():
@@ -249,31 +14,6 @@ def test_serve_config_rejects_shed_as_max_batch():
     taking empty batches forever; ServeConfig refuses it loudly."""
     with pytest.raises(ValueError, match="shed"):
         ServeConfig(max_batch=0)
-
-
-def test_trainer_compute_observer_feeds_planner_once_per_step():
-    """The straggler-stats feed of the joint re-planner: each *newly
-    completed* step reports (es, flops, dt) exactly once -- replayed steps
-    after a restore must not double-feed the compute estimator."""
-    seen = []
-    with tempfile.TemporaryDirectory() as d:
-        t = _toy_trainer(
-            d,
-            fault_hook=_fault_once_at({3}),
-            compute_observer=lambda es, fl, dt: seen.append((es, fl, dt)),
-            es_name="b",
-            step_flops=2e9,
-        )
-        t.run({"w": jnp.zeros(())}, 6, resume=False)
-    assert len(seen) == 6  # one per unique step despite the replay
-    assert all(es == "b" and fl == 2e9 and dt > 0 for es, fl, dt in seen)
-    # and the samples drive a ComputeRateEstimator as wired in production
-    from repro.core import ComputeRateEstimator
-
-    est = ComputeRateEstimator({"b": 1e12}, alpha=1.0)
-    for es, fl, dt in seen:
-        est.observe(es, fl, dt)
-    assert est.rate("b") == pytest.approx(seen[-1][1] / seen[-1][2])
 
 
 def test_batching_engine_es_timing_hook():
@@ -291,14 +31,6 @@ def test_batching_engine_es_timing_hook():
     assert seen == [("e1", 3.2e9, 0.004), ("e2", 1.6e9, 0.004)]
     # unwired engine: silently ignored
     BatchingEngine(jax.jit(lambda b: b), ServeConfig()).observe_es_time("e1", 1.0, 1.0)
-
-
-def test_losses_decrease_smoke():
-    from repro.runtime.train import train_smoke
-
-    out = train_smoke("vit-l16", n_steps=8)
-    assert out["steps"] == 8
-    assert all(np.isfinite(out["losses"]))
 
 
 def test_batching_engine_deadlines():

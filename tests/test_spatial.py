@@ -2,7 +2,7 @@
 
 Single-device semantic checks run in-process; the SPMD shard_map checks run in
 a subprocess with 8 forced host devices (this process keeps the default single
-CPU device, as the dry-run instructions require).
+CPU device).
 """
 import os
 import subprocess
